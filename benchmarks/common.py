@@ -23,16 +23,40 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
+import sys
 import time
 from pathlib import Path
 from typing import Callable, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from repro.obs import trace as obs_trace
 
 ROWS: List[Tuple[str, float, str]] = []
 
+ROOT = Path(__file__).resolve().parents[1]
 # default artifact directory — every sweep runner writes here
-OUT = Path(__file__).resolve().parents[1] / "experiments" / "sim"
+OUT = ROOT / "experiments" / "sim"
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed path, since the path is part of every cache key
+CACHE_DIR = ROOT / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one directory.
+
+    Called by entry points only (``chip_smoke.py``, ``benchmarks.run``
+    and every runner's :func:`parse_opts`), never on library import or
+    in tests.  ``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise it
+    is set to :data:`CACHE_DIR`, so worker processes inherit it.  Sets
+    JAX's config only where JAX is already imported: a parent that
+    launches chip workers must not import it on their behalf.
+    """
+    path = os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(CACHE_DIR))
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def emit(name: str, us_per_call: float, derived: str) -> None:
@@ -95,7 +119,8 @@ def parse_opts(
     axis: str = "cells",
 ) -> BenchOpts:
     """The shared runner CLI (``--only``, ``--seeds``, ``--devices``,
-    ``--out``)."""
+    ``--out``); also places the compile cache (:func:`use_compile_cache`)."""
+    use_compile_cache()
     ap = argparse.ArgumentParser(prog=prog, description=description)
     ap.add_argument(
         "--only",
@@ -133,19 +158,45 @@ def parse_opts(
     )
 
 
-def _env_meta() -> dict:
-    """Environment provenance every artifact records (ISSUE 8): numbers
-    without the stack + device that produced them aren't comparable."""
-    meta = {}
-    try:
-        import jax
+def rows_equal(ra, rb) -> bool:
+    """Two sweep rows (``SimResult`` or ``SummaryResult``) bitwise equal
+    on every result field; ``config`` and ``final_cache`` are skipped."""
+    names = (
+        ra._fields
+        if hasattr(ra, "_fields")
+        else tuple(f.name for f in dataclasses.fields(ra))
+    )
+    for name in names:
+        if name in ("config", "final_cache"):
+            continue
+        a, b = getattr(ra, name), getattr(rb, name)
+        if a is None or b is None:
+            if a is not b:
+                return False
+            continue
+        if not np.array_equal(np.asarray(a), np.asarray(b)):
+            return False
+    return True
 
-        meta["jax_version"] = jax.__version__
-        meta["device_kind"] = jax.devices()[0].device_kind
-        meta["n_devices"] = len(jax.devices())
-    except Exception:  # keep artifacts writable even if jax breaks late
-        pass
-    return meta
+
+def _env_meta() -> dict:
+    """Environment provenance every artifact records: numbers without
+    the stack + device that produced them aren't comparable.
+
+    A process that has not imported JAX records nothing here: it is a
+    launcher whose workers need the device, and asking for the devices
+    would make it hold them (it records what its workers report).
+    """
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return {}
+    devs = jax.devices()
+    return {
+        "jax_version": jax.__version__,
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "n_devices": len(devs),
+    }
 
 
 def _utc(ts: float) -> str:

@@ -11,6 +11,8 @@ import argparse
 import sys
 import time
 
+from benchmarks.common import use_compile_cache
+
 SECTIONS = {
     "paper": "benchmarks.paper_claims",        # E1+E2 (Fig 3/4 + §VI table)
     "theory": "benchmarks.theory",             # E3
@@ -58,6 +60,7 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown section(s): {', '.join(unknown)}; "
                  f"available: {', '.join(SECTIONS)} (try --list)")
+    use_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.time()
     for name in names:

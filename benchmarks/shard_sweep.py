@@ -43,7 +43,8 @@ from typing import Optional
 
 import numpy as np
 
-from benchmarks.common import (Artifact, BenchOpts, emit, parse_opts)
+from benchmarks.common import (Artifact, BenchOpts, emit, parse_opts,
+                               rows_equal)
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -65,9 +66,11 @@ MEM_NS = (100_000, 1_000_000)
 TARGETS = (0.5, 400.0)
 SECTIONS = ("parity", "scaling", "memory", "ring")
 _TAG = "E11-RESULT "
+# what every worker result line carries about where it ran
+_ENV_KEYS = ("jax_version", "platform", "device_kind")
 
 
-def _spec(n=N_KEYS, t=T, seeds=SEEDS, devices=1, scenarios=SCENARIOS):
+def fleet_spec(n=N_KEYS, t=T, seeds=SEEDS, devices=1, scenarios=SCENARIOS):
     from repro.core import SimConfig, SweepSpec, make_workload
 
     wls = tuple(
@@ -100,25 +103,6 @@ def _rss_mb() -> float:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
 
 
-def _rows_equal(ra, rb) -> bool:
-    names = (
-        ra._fields
-        if hasattr(ra, "_fields")
-        else tuple(f.name for f in __import__("dataclasses").fields(ra))
-    )
-    for name in names:
-        if name in ("config", "final_cache"):
-            continue
-        a, b = getattr(ra, name), getattr(rb, name)
-        if a is None or b is None:
-            if a is not b:
-                return False
-            continue
-        if not np.array_equal(np.asarray(a), np.asarray(b)):
-            return False
-    return True
-
-
 # --------------------------------------------------------------------------
 # Worker modes (run in subprocesses so each gets its own device count)
 # --------------------------------------------------------------------------
@@ -132,7 +116,7 @@ def _worker(req: dict) -> dict:
 
     seeds = tuple(range(req["seeds"]))
     if req["mode"] == "scaling":
-        spec = _spec(
+        spec = fleet_spec(
             n=req["n"], t=req["t"], seeds=seeds, devices=req["devices"]
         )
         t0 = time.perf_counter()
@@ -165,13 +149,13 @@ def _worker(req: dict) -> dict:
     if req["mode"] == "parity":
         n_dev = req["devices"]
         single = run_sweep(
-            _spec(n=req["n"], t=req["t"], seeds=seeds, devices=1)
+            fleet_spec(n=req["n"], t=req["t"], seeds=seeds, devices=1)
         )
         sharded = run_sweep(
-            _spec(n=req["n"], t=req["t"], seeds=seeds, devices=n_dev)
+            fleet_spec(n=req["n"], t=req["t"], seeds=seeds, devices=n_dev)
         )
         ok = set(single.cells) == set(sharded.cells) and all(
-            _rows_equal(single.cells[c], sharded.cells[c])
+            rows_equal(single.cells[c], sharded.cells[c])
             for c in single.cells
         )
         return {
@@ -183,7 +167,7 @@ def _worker(req: dict) -> dict:
             "shard_traces": _SHARD_TRACES[0],
         }
     if req["mode"] == "memory":
-        spec = _spec(
+        spec = fleet_spec(
             n=req["n"],
             t=req["t"],
             seeds=seeds,
@@ -195,9 +179,10 @@ def _worker(req: dict) -> dict:
     raise ValueError(f"unknown worker mode {req['mode']!r}")
 
 
-def _launch(req: dict, devices: int) -> dict:
+def _launch(req: dict, devices: int, meta: dict) -> dict:
     """Run one worker in a fresh subprocess with its own device count
-    (XLA locks the host platform device count at first jax init)."""
+    (XLA locks the host platform device count at first jax init), and
+    record in ``meta`` the stack and device it reports it ran on."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.pathsep.join(
@@ -224,7 +209,10 @@ def _launch(req: dict, devices: int) -> dict:
         )
     for line in reversed(proc.stdout.splitlines()):
         if line.startswith(_TAG):
-            return json.loads(line[len(_TAG):])
+            res = json.loads(line[len(_TAG):])
+            for key in _ENV_KEYS:
+                meta[key] = res.pop(key)
+            return res
     raise RuntimeError(
         f"shard_sweep worker {req['mode']!r} produced no result line"
     )
@@ -290,13 +278,12 @@ def run(opts: Optional[BenchOpts] = None) -> None:
     if opts.devices > 1:
         devs = tuple(sorted({1, opts.devices}))
     devs = tuple(d for d in devs if d <= (os.cpu_count() or 1) * 8)
-    import jax
-
+    # this process never touches JAX: each worker needs the device to
+    # itself, and the stack + device in the artifact are the ones the
+    # workers report (_launch)
     art = Artifact("BENCH_shard.json", opts.out)
     doc: dict = {
         "meta": {
-            "jax": jax.__version__,
-            "backend": jax.default_backend(),
             "cpus": os.cpu_count(),
             "n_keys": N_KEYS,
             "m": M,
@@ -323,6 +310,7 @@ def run(opts: Optional[BenchOpts] = None) -> None:
                 "devices": n_dev,
             },
             devices=n_dev,
+            meta=doc["meta"],
         )
         doc["parity"] = res
         art.write(doc)
@@ -347,6 +335,7 @@ def run(opts: Optional[BenchOpts] = None) -> None:
                     "devices": d,
                 },
                 devices=d,
+                meta=doc["meta"],
             )
             if base is None:
                 base = res["run_s"]
@@ -374,6 +363,7 @@ def run(opts: Optional[BenchOpts] = None) -> None:
                     "devices": 1,
                 },
                 devices=1,
+                meta=doc["meta"],
             )
             rss.append(res["rss_mb"])
             doc["memory"]["runs"].append(res)
@@ -407,7 +397,12 @@ def run(opts: Optional[BenchOpts] = None) -> None:
 def main(argv=None) -> None:
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv[:1] == ["--worker"]:
+        import jax
+
         out = _worker(json.loads(argv[1]))
+        dev = jax.devices()[0]
+        env = (jax.__version__, dev.platform, dev.device_kind)
+        out.update(zip(_ENV_KEYS, env))
         print(_TAG + json.dumps(out), flush=True)
         return
     run(

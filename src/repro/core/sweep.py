@@ -14,8 +14,7 @@ shim on top of this module.
 
 Sharding (DESIGN.md §12).  ``SweepSpec(devices=n)`` partitions the SEED
 axis of each (policy, controller) batch over an n-device mesh with
-``shard_map`` (the ``jax.experimental.shard_map`` compat split mirrors
-``repro.models.moe``): workload grids are replicated (``P()`` — they are
+``jax.shard_map``: workload grids are replicated (``P()`` — they are
 seed-independent, and the per-workload feasible-set gather stays one
 batched call *per device*, never O(cells)), while every leaf of the
 stacked ``SimState`` is split on its leading seed axis.  Each device
@@ -242,29 +241,6 @@ class SweepResult:
 # ---------------------------------------------------------------------------
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """PR 3 compat split: ``jax.shard_map`` (>= 0.5, check_vma) vs
-    ``jax.experimental.shard_map`` (pre-rename, check_rep) — same idiom
-    as ``repro.models.moe``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        fn,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 # Trace counter mirroring sim._SWEEP_TRACES: one (re)compile per
 # (config, metrics, devices), regardless of #seeds/#workloads.
 _SHARD_TRACES = [0]
@@ -296,11 +272,12 @@ def _run_scan_sweep_sharded(
     def body(sts, k, mk, w):
         return sim._sweep_vmapped(cfg, sts, k, mk, w, metrics)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(P("dev"), P(), P(), P()),
         out_specs=P(None, "dev"),
+        check_vma=False,
     )
     return fn(states, keys, mask, is_write)
 

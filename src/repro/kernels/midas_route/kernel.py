@@ -47,6 +47,21 @@ NEG_INF = -2.0**30
 ROUTE_MODES = ("power_of_d", "midas", "chbl")
 
 
+def _first_true(mask, n: int):
+    """Lowest index along the last axis where ``mask`` holds (``n`` when
+    none does).  Every arg-reduction in this module is spelled with it
+    (``_first_true(x == min(x))`` is ``argmin``): Mosaic's own
+    arg-reductions take float32 only and promise no tie order, while
+    bitwise parity with ``ref`` needs jnp's lowest-index rule."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, mask.shape, mask.ndim - 1)
+    return jnp.min(jnp.where(mask, cols, n), axis=-1)
+
+
+def _argmin(x):
+    """``jnp.argmin`` over the last axis, lowest index on ties."""
+    return _first_true(x == jnp.min(x, axis=-1, keepdims=True), x.shape[-1])
+
+
 def _iter_topk(logits, kd: int, tile: int, E: int):
     """Top-``kd`` ids/vals per row via iterated argmax/mask (VPU-friendly;
     ties resolve to the lowest index, matching ``jax.lax.top_k``)."""
@@ -54,13 +69,21 @@ def _iter_topk(logits, kd: int, tile: int, E: int):
     ids = []
     vals = []
     for _ in range(kd):
-        idx = jnp.argmax(masked, axis=-1).astype(jnp.int32)  # (tile,)
         val = jnp.max(masked, axis=-1)
+        idx = _first_true(masked == val[:, None], E)  # (tile,)
         cols = jax.lax.broadcasted_iota(jnp.int32, (tile, E), 1)
         masked = jnp.where(cols == idx[:, None], NEG_INF, masked)
         ids.append(idx)
         vals.append(val)
     return jnp.stack(ids, axis=1), jnp.stack(vals, axis=1)
+
+
+def _take_1h(load, ids, E: int):
+    """``load[ids]`` for a (E,) VMEM vector as a one-hot contraction over
+    an E-wide iota (Mosaic lowers no vector-indexed gather); exact, since
+    each row sums one value and zeros."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, ids.shape + (E,), ids.ndim)
+    return jnp.sum(jnp.where(ids[..., None] == iota, load, 0.0), axis=-1)
 
 
 def _body(
@@ -86,7 +109,7 @@ def _body(
 
     alt_ids = cand[:, k:]  # (tile, d)
     alt_vals = cvals[:, k:]
-    alt_load = load[alt_ids]
+    alt_load = _take_1h(load, alt_ids, E)
     alt_used = jnp.zeros((tile, d), jnp.bool_)
 
     chosen_e = []
@@ -95,17 +118,18 @@ def _body(
     for i in range(k):
         prim = cand[:, i]
         prim_val = cvals[:, i]
+        prim_load = _take_1h(load, prim, E)
         ok = (
             ~alt_used
-            & (alt_load <= load[prim][:, None] - delta_l)
+            & (alt_load <= prim_load[:, None] - delta_l)
             & (alt_vals >= prim_val[:, None] - gate_slack)
         )
         a_load = jnp.where(ok, alt_load, jnp.inf)
-        best = jnp.argmin(a_load, axis=-1)
+        best = _argmin(a_load)
         has = jnp.any(ok, axis=-1)
         benefit = jnp.where(
             has,
-            load[prim] - jnp.min(a_load, axis=-1),
+            prim_load - jnp.min(a_load, axis=-1),
             -jnp.inf,
         )
         steer = has & (benefit >= delta_l)
@@ -118,7 +142,7 @@ def _body(
         alt_used = alt_used | (steer[:, None] & sel)
         chosen_e.append(e_i)
         chosen_v.append(v_i)
-        steer_fl.append(steer)
+        steer_fl.append(steer.astype(jnp.int32))
 
     ce = jnp.stack(chosen_e, axis=1)
     cv = jnp.stack(chosen_v, axis=1)
@@ -129,7 +153,7 @@ def _body(
 
     experts_ref[...] = ce.astype(jnp.int32)
     weights_ref[...] = w.astype(jnp.float32)
-    steered_ref[...] = jnp.stack(steer_fl, axis=1).astype(jnp.int32)
+    steered_ref[...] = jnp.stack(steer_fl, axis=1)
 
 
 def _cand_body(logits_ref, cand_ref, vals_ref, *, kd: int, E: int, tile: int):
@@ -277,17 +301,15 @@ def _route_body(
     tie = tie_ref[...].astype(jnp.float32)
     load = load_ref[...].astype(jnp.float32)[0]  # (m,)
 
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tile, d_max, m), 2)
-    oh = feas[..., None] == iota
-    lf = jnp.sum(jnp.where(oh, load, 0.0), axis=-1)  # L_view[feas]
+    lf = _take_1h(load, feas, m)  # L_view[feas]
 
     ok_any = jnp.zeros((tile,), jnp.int32)
     if mode == "power_of_d":
         loadv = jnp.where(samp, lf, jnp.inf)
-        slot = jnp.argmin(loadv + tie, axis=-1)
+        slot = _argmin(loadv + tie)
     elif mode == "midas":
         p50 = p50_ref[...].astype(jnp.float32)[0]
-        p50f = jnp.sum(jnp.where(oh, p50, 0.0), axis=-1)
+        p50f = _take_1h(p50, feas, m)
         delta_l = scal_ref[0, 0]
         delta_t = scal_ref[0, 1]
         # slot 0 IS the primary: lf[:, :1] == L_view[feas[:, 0]]
@@ -297,15 +319,12 @@ def _route_body(
             & (p50f <= p50f[:, :1] - delta_t)
         )
         loadv = jnp.where(ok, lf, jnp.inf)
-        slot = jnp.argmin(loadv + tie, axis=-1)
+        slot = _argmin(loadv + tie)
         ok_any = jnp.any(ok, axis=-1).astype(jnp.int32)
     elif mode == "chbl":
         cap = scal_ref[0, 2]
-        under = lf <= cap
-        first_under = jnp.argmax(under, axis=-1)
-        least_loaded = jnp.argmin(lf, axis=-1)
-        has_under = jnp.any(under, axis=-1)
-        slot = jnp.where(has_under, first_under, least_loaded)
+        first_under = _first_true(lf <= cap, d_max)
+        slot = jnp.where(first_under < d_max, first_under, _argmin(lf))
     else:  # pragma: no cover - guarded by route_select
         raise ValueError(f"unknown route mode {mode!r}")
 

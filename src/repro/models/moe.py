@@ -201,21 +201,12 @@ def moe_apply_sharded(p, cfg: ArchConfig, x: jnp.ndarray,
     from jax.sharding import PartitionSpec as P
     out_specs = (x_spec, MoEAux(load=P(), drop_rate=P(), steer_rate=P(),
                                 aux_loss=P()))
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(
-            local, mesh=mesh,
-            in_specs=(p_specs, x_spec, P()),
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    else:  # jax < 0.5: pre-rename API (check_rep) under jax.experimental
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(
-            local, mesh=mesh,
-            in_specs=(p_specs, x_spec, P()),
-            out_specs=out_specs,
-            check_rep=False,
-        )
+    mapped = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(p_specs, x_spec, P()),
+        out_specs=out_specs,
+        check_vma=False,
+    )
     y, aux = mapped(p, x, load_ewma if load_ewma is not None
                     else jnp.ones((E,), jnp.float32))
     return y, aux

@@ -282,6 +282,27 @@ def test_midas_route_fmax_capped_matches_ref(T, E, k, d, f_max, tile):
     np.testing.assert_array_equal(np.asarray(s_k), np.asarray(s_ref))
 
 
+@pytest.mark.parametrize("case", ["tied_alt_loads", "tied_logits"])
+def test_midas_route_single_pass_ties_match_ref(case):
+    """Single-pass (f_max=1) kernel vs ref where its selections tie:
+    alternates with equal loads (the steer target is the lowest slot)
+    and equal gate logits (top-(k+d) keeps the lowest expert id)."""
+    T, E, k, d = 256, 16, 4, 4
+    logits, _ = _mr_inputs(T, E)
+    load = jnp.asarray([6.0, 0.0, 3.0, 0.0] * 4)
+    if case == "tied_logits":
+        # + 0.0 folds -0.0 into 0.0: top_k orders the two, == does not
+        logits = jnp.round(logits) + 0.0
+    e_ref, w_ref, s_ref = mr_ref.midas_dispatch(logits, load, k, d)
+    e_k, w_k, s_k = mr_kernel.midas_dispatch(
+        logits, load, k, d, tile=128, interpret=True)
+    assert bool(np.asarray(s_ref).any())
+    np.testing.assert_array_equal(np.asarray(e_k), np.asarray(e_ref))
+    np.testing.assert_allclose(np.asarray(w_k), np.asarray(w_ref),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(s_k), np.asarray(s_ref))
+
+
 def test_midas_route_kernel_deff_zero_falls_back():
     """d_eff <= 0 (k + d spans all experts) collapses to plain top-k on
     every path — kernel, ref, and the ops wrapper agree."""
@@ -403,22 +424,43 @@ def test_route_select_midas_matches_jnp(R, m, d_max, tile):
     np.testing.assert_array_equal(np.asarray(got_any), np.asarray(want_any))
 
 
-@pytest.mark.parametrize("R,m,d_max,tile", RS_CASES)
-def test_route_select_chbl_matches_jnp(R, m, d_max, tile):
-    feas, load, _, _ = _rs_inputs(R, m, d_max)
-    cap = 1.25 * (jnp.mean(load) + 1.0)
+def _check_chbl(feas, load, cap, tile):
+    """route_select chbl vs the jnp policy expression for one cap."""
     Lf = load[feas]
     under = Lf <= cap
     slot = jnp.where(jnp.any(under, axis=1), jnp.argmax(under, axis=1),
                      jnp.argmin(Lf, axis=1))
     want = jnp.take_along_axis(feas, slot[:, None], axis=1)[:, 0]
-    scal = jnp.stack([jnp.zeros(()), jnp.zeros(()), cap,
+    scal = jnp.stack([jnp.zeros(()), jnp.zeros(()), jnp.float32(cap),
                       jnp.zeros(())]).reshape(1, 4)
     got, _ = mr_kernel.route_select(
         feas, load, load, jnp.zeros(feas.shape, jnp.int32),
         jnp.zeros(feas.shape, jnp.float32), scal, mode="chbl", tile=tile,
         interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    return np.asarray(under)
+
+
+@pytest.mark.parametrize("R,m,d_max,tile", RS_CASES)
+def test_route_select_chbl_matches_jnp(R, m, d_max, tile):
+    feas, load, _, _ = _rs_inputs(R, m, d_max)
+    _check_chbl(feas, load, 1.25 * (jnp.mean(load) + 1.0), tile)
+
+
+@pytest.mark.parametrize("case", ["none_under", "several_under"])
+def test_route_select_chbl_tie_rules(case):
+    """The two branches of chbl's slot choice at their tie rules, on
+    integer loads so that ties are common: with no slot under the cap
+    the least-loaded fallback takes the lowest tied slot, and with
+    several under the cap the lowest such slot wins."""
+    feas, _, _, _ = _rs_inputs(256, 8, 4)
+    load = jnp.asarray([3.0, 1.0, 3.0, 1.0, 5.0, 3.0, 1.0, 5.0])
+    cap = 0.0 if case == "none_under" else 3.0
+    under = _check_chbl(feas, load, cap, 128)
+    if case == "none_under":
+        assert not under.any()
+    else:
+        assert (under.sum(axis=1) >= 2).any()
 
 
 def test_route_select_rejects_unknown_mode():
