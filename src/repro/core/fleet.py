@@ -52,6 +52,7 @@ from __future__ import annotations
 import math
 from typing import NamedTuple, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import cache as cache_lib
@@ -200,32 +201,37 @@ def lookup_fleet(
         exp_view, ver_view, sh.global_version[keys], mask, is_write, now_ms
     )
 
-    # --- converged-table effects: identical to the shared model ----------
-    new_sh, eff = cache_lib.apply_batch(
-        sh,
-        keys,
-        mask,
-        is_write,
-        hit,
-        stale,
-        now_ms,
-        mode=mode,
-        lease_ms=lease_ms,
-        rtt_ms=rtt_ms,
-        p_star=p_star,
-        avail=avail,
-    )
+    # the per-key table writes (``scatter``) and the snapshot push
+    # (``snapshot``) run under scopes of their own: the device trace
+    # tells them apart (repro.obs.trace.phase_map)
+    with jax.named_scope("scatter"):
+        # --- converged-table effects: identical to the shared model ------
+        new_sh, eff = cache_lib.apply_batch(
+            sh,
+            keys,
+            mask,
+            is_write,
+            hit,
+            stale,
+            now_ms,
+            mode=mode,
+            lease_ms=lease_ms,
+            rtt_ms=rtt_ms,
+            p_star=p_star,
+            avail=avail,
+        )
 
-    # --- gossip log: invalidations first, installs win on collision ------
-    # (same intra-tick order as apply_batch's table scatters)
-    lev = state.last_event_ms.at[eff.inv_keys].set(now_ms, mode="drop")
-    lor = state.last_origin.at[eff.inv_keys].set(proxy, mode="drop")
-    lev = lev.at[eff.ins_keys].set(now_ms, mode="drop")
-    lor = lor.at[eff.ins_keys].set(proxy, mode="drop")
+        # --- gossip log: invalidations first, installs win on collision --
+        # (same intra-tick order as apply_batch's table scatters)
+        lev = state.last_event_ms.at[eff.inv_keys].set(now_ms, mode="drop")
+        lor = state.last_origin.at[eff.inv_keys].set(proxy, mode="drop")
+        lev = lev.at[eff.ins_keys].set(now_ms, mode="drop")
+        lor = lor.at[eff.ins_keys].set(proxy, mode="drop")
 
     # --- push the post-tick snapshot; this slot is re-read at tick+D -----
-    lag_e = state.lag_expiry.at[slot].set(new_sh.expiry_ms)
-    lag_v = state.lag_version.at[slot].set(new_sh.cached_version)
+    with jax.named_scope("snapshot"):
+        lag_e = state.lag_expiry.at[slot].set(new_sh.expiry_ms)
+        lag_v = state.lag_version.at[slot].set(new_sh.cached_version)
 
     # --- per-proxy counters: segment-sum flags onto the proxy axis -------
     # miss/bypassed come from apply_batch's effect vectors, so per-proxy

@@ -53,6 +53,9 @@ from repro.core.workloads import Workload
 from repro.kernels import common as kernels_common
 from repro.obs import trace as obs_trace
 
+# JAX's compile events reach the flight recorder from here on
+obs_trace.listen_compile()
+
 # Snapshot of the registry at import time; prefer policies.available().
 POLICIES = policy_lib.available()
 
@@ -679,6 +682,8 @@ def _tick(
     # reference keeps its in-tick per-wave gathers, as pre-PR.  With a
     # compiled fault program (``fc``, a trace-time constant), this
     # tick's fault rows (faults.FaultXs) arrive as the last xs entry.
+    # Each phase runs under a named scope (``obs_trace.PROGRAM_PHASES``):
+    # metadata on the compiled instructions only, results unchanged.
     if fc is not None:
         inputs, fx = inputs[:-1], inputs[-1]
     else:
@@ -698,191 +703,200 @@ def _tick(
     # decisions flap on per-tick noise); the slow branch resets the
     # window after the controller consumed it.  Controllers that ignore
     # the signal cost nothing (XLA DCE).
-    state = state._replace(
-        win_writes=state.win_writes
-        + jnp.sum((is_write & mask).astype(jnp.float32)),
-        win_events=state.win_events
-        + jnp.sum(mask.astype(jnp.float32)),
-    )
+    with jax.named_scope("tick/control"):
+        state = state._replace(
+            win_writes=state.win_writes
+            + jnp.sum((is_write & mask).astype(jnp.float32)),
+            win_events=state.win_events
+            + jnp.sum(mask.astype(jnp.float32)),
+        )
 
     # --- fault context: remap invalidation BEFORE any stage serves -------
     finfo = None
     if fx is not None:
-        finfo = faults_lib.tick_info(fc, fx)
-        if finfo.inval is not None:
-            state = state._replace(
-                mw=tuple(
-                    mw.on_fault(ms, finfo, cfg)
-                    for mw, ms in zip(mws, state.mw)
+        with jax.named_scope("tick/faults"):
+            finfo = faults_lib.tick_info(fc, fx)
+            if finfo.inval is not None:
+                state = state._replace(
+                    mw=tuple(
+                        mw.on_fault(ms, finfo, cfg)
+                        for mw, ms in zip(mws, state.mw)
+                    )
                 )
-            )
 
     # --- middleware pipeline: stages may absorb requests at the proxy ----
     absorbed = jnp.zeros((), jnp.float32)
     mw_states = list(state.mw)
-    for i, mw in enumerate(mws):
-        batch = mw_lib.BatchView(
-            keys=keys,
-            mask=mask,
-            is_write=is_write,
-            now_ms=now_ms,
-            rng=jax.random.fold_in(r_mw, i),
-            faults=finfo,
-        )
-        mw_states[i], mask, took = mw.on_batch(mw_states[i], batch, cfg)
-        absorbed = absorbed + took
+    with jax.named_scope("tick/middleware"):
+        for i, mw in enumerate(mws):
+            with jax.named_scope(mw.name):
+                batch = mw_lib.BatchView(
+                    keys=keys,
+                    mask=mask,
+                    is_write=is_write,
+                    now_ms=now_ms,
+                    rng=jax.random.fold_in(r_mw, i),
+                    faults=finfo,
+                )
+                mw_states[i], mask, took = mw.on_batch(
+                    mw_states[i], batch, cfg
+                )
+                absorbed = absorbed + took
     state = state._replace(mw=tuple(mw_states))
 
     # --- route in waves (scan engine; unrolled reference on request) -----
-    keysg = _wave_split(cfg, keys)
-    maskg = _wave_split(cfg, mask)
-    knobs = controller.view(state.ctrl)
-    if cfg.unroll_waves:
-        ps, arrivals, stats = _route_waves_unrolled(
-            cfg,
-            ring,
-            policy,
-            state,
-            knobs,
-            t,
-            now_ms,
-            r_route,
-            keysg,
-            maskg,
-            fc,
-            fx,
-        )
-    else:
-        ps, arrivals, stats = _route_waves_scan(
-            cfg,
-            ring,
-            policy,
-            state,
-            knobs,
-            t,
-            now_ms,
-            r_route,
-            keysg,
-            maskg,
-            feasg,
-        )
+    with jax.named_scope("tick/route"):
+        keysg = _wave_split(cfg, keys)
+        maskg = _wave_split(cfg, mask)
+        knobs = controller.view(state.ctrl)
+        if cfg.unroll_waves:
+            ps, arrivals, stats = _route_waves_unrolled(
+                cfg,
+                ring,
+                policy,
+                state,
+                knobs,
+                t,
+                now_ms,
+                r_route,
+                keysg,
+                maskg,
+                fc,
+                fx,
+            )
+        else:
+            ps, arrivals, stats = _route_waves_scan(
+                cfg,
+                ring,
+                policy,
+                state,
+                knobs,
+                t,
+                now_ms,
+                r_route,
+                keysg,
+                maskg,
+                feasg,
+            )
     state = state._replace(policy=ps)
 
     # --- queue dynamics: constant-rate servers, work-conserving ----------
-    L = state.L + arrivals
-    if fc is not None and (fc.has_brownout or fc.has_downtime):
-        # ground-truth faults bite immediately: browned-out servers
-        # drain slower, dead servers not at all (their queue freezes
-        # until rejoin)
-        rate = jnp.full((cfg.m,), cfg.serve_per_tick, jnp.float32)
-        if fc.has_brownout:
-            rate = rate * fx.scale
-        if fc.has_downtime:
-            rate = rate * fx.member.astype(jnp.float32)
-        served = jnp.minimum(L, rate)
-    else:
-        served = jnp.minimum(L, cfg.serve_per_tick)
-    L = L - served
-    lat_pred = (state.L + arrivals) * cfg.service_ms  # wait of new arrival
+    with jax.named_scope("tick/queues"):
+        L = state.L + arrivals
+        if fc is not None and (fc.has_brownout or fc.has_downtime):
+            # ground-truth faults bite immediately: browned-out servers
+            # drain slower, dead servers not at all (their queue freezes
+            # until rejoin)
+            rate = jnp.full((cfg.m,), cfg.serve_per_tick, jnp.float32)
+            if fc.has_brownout:
+                rate = rate * fx.scale
+            if fc.has_downtime:
+                rate = rate * fx.member.astype(jnp.float32)
+            served = jnp.minimum(L, rate)
+        else:
+            served = jnp.minimum(L, cfg.serve_per_tick)
+        L = L - served
+        # wait of a new arrival
+        lat_pred = (state.L + arrivals) * cfg.service_ms
 
     state = state._replace(L=L)
     t1 = t + 1  # post-tick clock, the cadence the control loops count on
-
     # --- telemetry ingest + fast control (every T_fast) ------------------
-    is_fast = (t1 % cfg.t_fast_ticks) == 0
-    sketch = telemetry.sketch_add(state.sketch, lat_pred)
+    with jax.named_scope("tick/control"):
+        is_fast = (t1 % cfg.t_fast_ticks) == 0
+        sketch = telemetry.sketch_add(state.sketch, lat_pred)
 
-    if cfg.fleet_routing:
-        # per-proxy views: each proxy polls on its own staggered phase, so
-        # the P views carry genuinely different staleness at any instant
-        state = state._replace(
-            L_hat_p=telemetry.ewma_staggered(
-                state.L_hat_p,
-                state.L,
-                t1,
-                cfg.t_fast_ticks,
-                ctrl_lib.ALPHA_FAST,
-            )
-        )
-
-    def _signals(s: SimState, B, p99, jitter) -> Signals:
-        # availability / membership telemetry: constants (full) on the
-        # zero-fault path, this tick's detected view under a schedule
-        if fx is None:
-            avail = jnp.ones(())
-            member = jnp.ones((cfg.m,))
-        else:
-            avail = fx.avail
-            member = fx.detected.astype(jnp.float32)
-        return Signals(
-            B=B,
-            p99=p99,
-            L_hat=s.L_hat,
-            views_p=s.L_hat_p,
-            write_mix=s.win_writes / jnp.maximum(s.win_events, 1.0),
-            jitter=jitter,
-            rtt_ms=cfg.rtt_ms,
-            avail=avail,
-            member=member,
-        )
-
-    def ingest(s: SimState) -> SimState:
-        # quantile extraction (a per-server sort) lives INSIDE the fast
-        # branch: with t unbatched the sort really runs once per fast
-        # interval, not every tick
-        p50_o, p99_o = telemetry.sketch_quantiles(s.sketch)
         if cfg.fleet_routing:
-            # one control loop fed by the fleet's consensus view
-            L_hat = ctrl_lib.consensus_view(s.L_hat_p, cfg.consensus)
-        else:
-            L_hat = telemetry.ewma(s.L_hat, s.L, ctrl_lib.ALPHA_FAST)
-        p50 = telemetry.ewma(s.p50_hat, p50_o, ctrl_lib.ALPHA_FAST)
-        p99 = telemetry.ewma(s.p99_hat, p99_o, ctrl_lib.ALPHA_FAST)
-        if fc is not None and fc.has_remap:
-            # survivors-only imbalance: a dead server's frozen queue
-            # must not pin B(t) for the whole outage
-            B = telemetry.imbalance_masked(L_hat, fx.detected)
-        else:
-            B = telemetry.imbalance(L_hat)
-        jit = jax.random.uniform(
-            jax.random.fold_in(s.rng, 3), (), minval=-1.0, maxval=1.0
-        )
-        s = s._replace(L_hat=L_hat, p50_hat=p50, p99_hat=p99)
-        ctrl, _ = controller.fast(
-            s.ctrl, _signals(s, B, jnp.max(p99), jit)
-        )
-        return s._replace(ctrl=ctrl)
+            # per-proxy views: each proxy polls on its own staggered phase, so
+            # the P views carry genuinely different staleness at any instant
+            state = state._replace(
+                L_hat_p=telemetry.ewma_staggered(
+                    state.L_hat_p,
+                    state.L,
+                    t1,
+                    cfg.t_fast_ticks,
+                    ctrl_lib.ALPHA_FAST,
+                )
+            )
 
-    state = state._replace(sketch=sketch)
-    state = jax.lax.cond(is_fast, ingest, lambda s: s, state)
+        def _signals(s: SimState, B, p99, jitter) -> Signals:
+            # availability / membership telemetry: constants (full) on the
+            # zero-fault path, this tick's detected view under a schedule
+            if fx is None:
+                avail = jnp.ones(())
+                member = jnp.ones((cfg.m,))
+            else:
+                avail = fx.avail
+                member = fx.detected.astype(jnp.float32)
+            return Signals(
+                B=B,
+                p99=p99,
+                L_hat=s.L_hat,
+                views_p=s.L_hat_p,
+                write_mix=s.win_writes / jnp.maximum(s.win_events, 1.0),
+                jitter=jitter,
+                rtt_ms=cfg.rtt_ms,
+                avail=avail,
+                member=member,
+            )
 
-    is_slow = (t1 % cfg.t_slow_ticks) == 0
+        def ingest(s: SimState) -> SimState:
+            # quantile extraction (a per-server sort) lives INSIDE the fast
+            # branch: with t unbatched the sort really runs once per fast
+            # interval, not every tick
+            p50_o, p99_o = telemetry.sketch_quantiles(s.sketch)
+            if cfg.fleet_routing:
+                # one control loop fed by the fleet's consensus view
+                L_hat = ctrl_lib.consensus_view(s.L_hat_p, cfg.consensus)
+            else:
+                L_hat = telemetry.ewma(s.L_hat, s.L, ctrl_lib.ALPHA_FAST)
+            p50 = telemetry.ewma(s.p50_hat, p50_o, ctrl_lib.ALPHA_FAST)
+            p99 = telemetry.ewma(s.p99_hat, p99_o, ctrl_lib.ALPHA_FAST)
+            if fc is not None and fc.has_remap:
+                # survivors-only imbalance: a dead server's frozen queue
+                # must not pin B(t) for the whole outage
+                B = telemetry.imbalance_masked(L_hat, fx.detected)
+            else:
+                B = telemetry.imbalance(L_hat)
+            jit = jax.random.uniform(
+                jax.random.fold_in(s.rng, 3), (), minval=-1.0, maxval=1.0
+            )
+            s = s._replace(L_hat=L_hat, p50_hat=p50, p99_hat=p99)
+            ctrl, _ = controller.fast(
+                s.ctrl, _signals(s, B, jnp.max(p99), jit)
+            )
+            return s._replace(ctrl=ctrl)
 
-    def slow(s: SimState) -> SimState:
-        if fc is not None and fc.has_remap:
-            B_slow = telemetry.imbalance_masked(s.L_hat, fx.detected)
-        else:
-            B_slow = telemetry.imbalance(s.L_hat)
-        ctrl, k = controller.slow(
-            s.ctrl,
-            _signals(
-                s,
-                B_slow,
-                jnp.max(s.p99_hat),
-                jnp.zeros((), jnp.float32),
-            ),
-        )
-        return s._replace(
-            ctrl=ctrl,
-            mw=tuple(
-                mw.on_slow(ms, cfg, k) for mw, ms in zip(mws, s.mw)
-            ),
-            # window consumed: write-mix restarts for the next T_slow
-            win_writes=jnp.zeros((), jnp.float32),
-            win_events=jnp.zeros((), jnp.float32),
-        )
+        state = state._replace(sketch=sketch)
+        state = jax.lax.cond(is_fast, ingest, lambda s: s, state)
 
-    state = jax.lax.cond(is_slow, slow, lambda s: s, state)
+        is_slow = (t1 % cfg.t_slow_ticks) == 0
+
+        def slow(s: SimState) -> SimState:
+            if fc is not None and fc.has_remap:
+                B_slow = telemetry.imbalance_masked(s.L_hat, fx.detected)
+            else:
+                B_slow = telemetry.imbalance(s.L_hat)
+            ctrl, k = controller.slow(
+                s.ctrl,
+                _signals(
+                    s,
+                    B_slow,
+                    jnp.max(s.p99_hat),
+                    jnp.zeros((), jnp.float32),
+                ),
+            )
+            return s._replace(
+                ctrl=ctrl,
+                mw=tuple(
+                    mw.on_slow(ms, cfg, k) for mw, ms in zip(mws, s.mw)
+                ),
+                # window consumed: write-mix restarts for the next T_slow
+                win_writes=jnp.zeros((), jnp.float32),
+                win_events=jnp.zeros((), jnp.float32),
+            )
+
+        state = jax.lax.cond(is_slow, slow, lambda s: s, state)
 
     out = TickOut(
         L=L,
@@ -945,13 +959,14 @@ def _scan_inputs(
     if cfg.unroll_waves:
         base = (ticks, keys, mask, is_write)
     else:
-        keysg = _wave_split(cfg, keys)
-        if fc is not None:
-            feasg = faults_lib.feasible_by_epoch(
-                ring, keysg, cfg.d_max, fc
-            )
-        else:
-            feasg = hashring.feasible_set(ring, keysg, cfg.d_max)
+        with jax.named_scope("sweep/feasible"):
+            keysg = _wave_split(cfg, keys)
+            if fc is not None:
+                feasg = faults_lib.feasible_by_epoch(
+                    ring, keysg, cfg.d_max, fc
+                )
+            else:
+                feasg = hashring.feasible_set(ring, keysg, cfg.d_max)
         base = (ticks, feasg, keys, mask, is_write)
     if fc is not None:
         base = base + (faults_lib.make_xs(fc),)
@@ -1028,7 +1043,9 @@ def _sweep_vmapped(
                     pressure=out.pressure,
                     q_mean=jnp.mean(out.L),
                 )
-                return (s, _summary_update(acc, out)), ys
+                with jax.named_scope("tick/summary"):
+                    acc = _summary_update(acc, out)
+                return (s, acc), ys
 
             (final, acc), trace = jax.lax.scan(
                 tick, (st, _summary_init(cfg.m)), grids

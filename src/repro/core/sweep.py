@@ -376,32 +376,41 @@ def run_sweep(spec: SweepSpec) -> SweepResult:
                 workloads=len(wls),
                 seeds=len(spec.seeds),
             ) as sp:
-                if spec.devices > 1:
-                    states, pad = _pad_seed_axis(
-                        states, len(spec.seeds), spec.devices
-                    )
-                    final, outs = _run_scan_sweep_sharded(
-                        pcfg,
-                        states,
-                        keys,
-                        mask,
-                        is_write,
-                        spec.metrics,
-                        spec.devices,
-                    )
-                else:
-                    pad = 0
-                    final, outs = sim._run_scan_sweep(
-                        pcfg, states, keys, mask, is_write, spec.metrics
-                    )
+                with obs_trace.span("sweep/dispatch", cat="dispatch"):
+                    if spec.devices > 1:
+                        states, pad = _pad_seed_axis(
+                            states, len(spec.seeds), spec.devices
+                        )
+                        fn = _run_scan_sweep_sharded
+                        args = (
+                            pcfg,
+                            states,
+                            keys,
+                            mask,
+                            is_write,
+                            spec.metrics,
+                            spec.devices,
+                        )
+                    else:
+                        pad = 0
+                        fn = sim._run_scan_sweep
+                        args = (
+                            pcfg, states, keys, mask, is_write, spec.metrics
+                        )
+                    final, outs = fn(*args)
                 # one transfer for the whole batch, sliced on host
-                outs = jax.device_get(outs)
-                if spec.metrics == "full":
-                    final = jax.device_get(final)
+                with obs_trace.span("sweep/transfer", cat="transfer"):
+                    outs = jax.device_get(outs)
+                    if spec.metrics == "full":
+                        final = jax.device_get(final)
                 sp["compiled"] = (
                     sim._SWEEP_TRACES[0] + _SHARD_TRACES[0] > traces0
                 )
-            del pad  # padded rows simply never get sliced below
+                if sp["compiled"] and obs_trace.RECORDER.enabled:
+                    # the executable the call just built: a cache hit,
+                    # no new trace (the trace counters stay put)
+                    obs_trace.register_program(fn.lower(*args).compile())
+            del pad, args  # padded rows simply never get sliced below
             with obs_trace.span(
                 "sweep/host_slice",
                 cat="host",

@@ -227,6 +227,7 @@ def midas_dispatch(
                 jax.ShapeDtypeStruct((Tp, k + d_eff), jnp.float32),
             ],
             interpret=interpret,
+            name="midas_dispatch_candidates",
         )(logits_p)
         return ref.steer_from_candidates(
             cand[:T],
@@ -265,6 +266,7 @@ def midas_dispatch(
             jax.ShapeDtypeStruct((Tp, k), jnp.int32),
         ],
         interpret=interpret,
+        name="midas_dispatch_onepass",
     )(logits_p, load2)
     return experts[:T], weights[:T], steered[:T].astype(bool)
 
@@ -393,6 +395,7 @@ def route_select(
             jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="route_select",
     )(
         _pad_rows(feas.astype(jnp.int32), Rp),
         _pad_rows(sampled.astype(jnp.int32), Rp),

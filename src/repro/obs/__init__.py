@@ -1,11 +1,16 @@
 # Flight-recorder observability plane (DESIGN.md §13).
 #
-# Three pieces, all host-side — nothing here touches jitted math, so
-# golden parity holds bit-for-bit with recording on or off:
+# Three pieces.  They run on the host, except the named scopes the
+# sweep program opens for its phases
+# (``repro.obs.trace.PROGRAM_PHASES``): metadata on its compiled
+# instructions, so golden parity holds bit-for-bit with recording on
+# or off:
 #
 # * ``repro.obs.trace``   — structured spans around the engine's
-#   compile/execute/host-slice phases, emitted as a JSONL event log
+#   dispatch/transfer/host-slice phases, emitted as a JSONL event log
 #   plus a Chrome-trace (``trace_event``) export viewable in Perfetto;
+#   JAX's compile events as counters; the program registry whose
+#   op-to-phase map attributes device time to the tick's phases;
 # * ``repro.obs.windows`` — the shared warmup/stable/cooldown windowing
 #   contract (EWMA-slope + variance plateau) every E-series runner uses
 #   so artifact cells carry stable-only statistics next to whole-run

@@ -104,3 +104,45 @@ def test_midas_dispatch_compiles_for_v5e(one_chip, E, k, f_max):
         return mr_kernel.midas_dispatch(lg, ld, k, 2, f_max=f_max)
 
     assert "tpu_custom_call" in _compile_text(fn, logits, load)
+
+
+def test_the_engine_names_the_routing_kernel_in_its_routing_phase(
+    one_chip, monkeypatch
+):
+    """``route_select_us_per_tick`` reads the device ops whose name
+    holds ``route_select``: the sweep program compiled for the chip
+    names the kernel's custom call so, inside the ``tick/route``
+    phase."""
+    import dataclasses
+    import re
+
+    from repro.core import SimConfig, make_workload, sim
+    from repro.kernels import common
+    from repro.obs import trace as obs_trace
+
+    # the program asks the backend it runs on; this one compiles for
+    # the described chip from a CPU process
+    monkeypatch.setattr(common, "interpret_mode", lambda: False)
+    cfg = SimConfig(m=8, N=4096, policy="midas", middleware=("cache",),
+                    route_impl="pallas")
+    wl = make_workload("bursty", T=12, m=8, seed=0, N=4096)
+    states = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs),
+        *[sim.init_state(dataclasses.replace(cfg, seed=s)) for s in (1, 2)],
+    )
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    grids = [shape(jnp.asarray(a)[None])
+             for a in (wl.keys, wl.mask, wl.is_write)]
+    text = sim._run_scan_sweep.lower(
+        cfg, jax.tree_util.tree_map(shape, states), *grids, "summary"
+    ).compile().as_text()
+    calls = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text
+    )
+    kernel = [c for c in calls if "route_select" in c]
+    assert kernel, calls
+    pmap = obs_trace.parse_phases(text)
+    assert {pmap.get(c) for c in kernel} == {("tick/route", "")}
