@@ -29,6 +29,16 @@ instead of merely shrinking TTLs — under mutation-dominated traffic
 caching only adds staleness risk and churn.  Bypassed installs are
 counted in ``CacheState.bypasses``.
 
+Layout: every per-key table is stored lane-tiled, shape
+``(N_pad // 128, 128)`` with ``N_pad`` = ``N`` rounded up to a multiple
+of 1024, so each grid cell's table is whole ``(8, 128)`` tiles — the
+layout the TPU's scatter works on in place.  An ``(N,)`` table batched
+under the sweep's vmap is not: every scatter into it copies the whole
+batch into a linear buffer and back.  Key ``k`` lives at
+:func:`key_index` ``(k >> 7, k & 127)``; the padding is never written
+and never read.  Readers outside this module and :mod:`repro.core.fleet`
+see the logical ``(N,)`` table through :func:`table_view`.
+
 This module holds the *converged shared table*: the state every proxy
 agrees on once gossip has propagated (the paper's space bound is
 O(m + C) per-namespace-key).  ``lookup_batch`` processes a tick against
@@ -55,12 +65,43 @@ GUARD_MIN_EVENTS = 64.0
 MODES = ("lease", "ttl_aggregate", "ttl_per_key")
 
 
+_LANE_BITS = 7
+LANES = 1 << _LANE_BITS  # 128
+_TILE_KEYS = 8 * LANES  # one (8, 128) tile of 32-bit words
+
+
+def table_shape(N: int) -> Tuple[int, int]:
+    """Lane-tiled shape ``(N_pad // 128, 128)`` of an (N,) per-key table."""
+    n_pad = -(-N // _TILE_KEYS) * _TILE_KEYS
+    return n_pad // LANES, LANES
+
+
+def to_table(x: jnp.ndarray, fill) -> jnp.ndarray:
+    """Tile a logical ``(..., N)`` array; the padding holds ``fill``."""
+    N = x.shape[-1]
+    rows, lanes = table_shape(N)
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, rows * lanes - N)]
+    x = jnp.pad(x, pad, constant_values=fill)
+    return x.reshape(x.shape[:-1] + (rows, lanes))
+
+
+def table_view(table: jnp.ndarray, N: int) -> jnp.ndarray:
+    """The logical ``(..., N)`` view of a lane-tiled table (or ring)."""
+    return table.reshape(table.shape[:-2] + (-1,))[..., :N]
+
+
+def key_index(keys: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(row, lane)`` of each key in a lane-tiled table."""
+    return keys >> _LANE_BITS, keys & (LANES - 1)
+
+
 class CacheState(NamedTuple):
-    expiry_ms: jnp.ndarray       # (N,) float32 absolute expiry time
-    cached_version: jnp.ndarray  # (N,) int32 version stored at insert
-    global_version: jnp.ndarray  # (N,) int32 authoritative version
-    last_write_ms: jnp.ndarray   # (N,) float32 last write time per key
-    key_hazard: jnp.ndarray      # (N,) float32 per-key ĥ (1/ms)
+    # per-key tables, lane-tiled (table_shape(N)); read via table_view
+    expiry_ms: jnp.ndarray       # float32 absolute expiry time
+    cached_version: jnp.ndarray  # int32 version stored at insert
+    global_version: jnp.ndarray  # int32 authoritative version
+    last_write_ms: jnp.ndarray   # float32 last write time per key
+    key_hazard: jnp.ndarray      # float32 per-key ĥ (1/ms)
     ttl_ms: jnp.ndarray          # () float32 aggregate adaptive TTL
     hazard: jnp.ndarray          # () float32 aggregate ĥ
     write_frac: jnp.ndarray      # () float32 EWMA of write mix W_c
@@ -75,12 +116,13 @@ class CacheState(NamedTuple):
 def init_cache(N: int, ttl_init_ms: float = 100.0) -> CacheState:
     z32 = jnp.zeros((), jnp.int32)
     zf = jnp.zeros((), jnp.float32)
+    shape = table_shape(N)
     return CacheState(
-        expiry_ms=jnp.zeros((N,), jnp.float32),
-        cached_version=jnp.full((N,), -1, jnp.int32),
-        global_version=jnp.zeros((N,), jnp.int32),
-        last_write_ms=jnp.full((N,), -1.0, jnp.float32),
-        key_hazard=jnp.zeros((N,), jnp.float32),
+        expiry_ms=jnp.zeros(shape, jnp.float32),
+        cached_version=jnp.full(shape, -1, jnp.int32),
+        global_version=jnp.zeros(shape, jnp.int32),
+        last_write_ms=jnp.full(shape, -1.0, jnp.float32),
+        key_hazard=jnp.zeros(shape, jnp.float32),
         ttl_ms=jnp.asarray(ttl_init_ms, jnp.float32),
         hazard=jnp.asarray(1e-6, jnp.float32),
         write_frac=zf,
@@ -112,8 +154,8 @@ class BatchEffects(NamedTuple):
     """Per-request effect vectors of one ``apply_batch`` tick — the
     single source both models derive counters and gossip events from."""
 
-    inv_keys: jnp.ndarray  # (R,) invalidation-event keys (sentinel N)
-    ins_keys: jnp.ndarray  # (R,) install-event keys (sentinel N)
+    inv_keys: jnp.ndarray  # (R,) invalidation-event keys (sentinel N_pad)
+    ins_keys: jnp.ndarray  # (R,) install-event keys (sentinel N_pad)
     miss: jnp.ndarray      # (R,) bool valid read misses
     bypassed: jnp.ndarray  # (R,) bool misses the guard served through
 
@@ -169,42 +211,45 @@ def apply_batch(
     wholesale at the next remap epoch, so installing only adds churn.
 
     Returns ``(new_cache, effects)``: the event-key vectors in
-    ``effects`` (sentinel ``N`` where no event) are the gossip payload
-    the fleet model propagates between proxies, and its flag vectors are
-    what per-proxy counters must be derived from so they always sum to
-    the aggregate counters updated here.
+    ``effects`` (sentinel ``N_pad`` where no event) are the gossip
+    payload the fleet model propagates between proxies, and its flag
+    vectors are what per-proxy counters must be derived from so they
+    always sum to the aggregate counters updated here.
     """
     assert mode in MODES, mode
-    N = cache.expiry_ms.shape[0]
+    # "no event" sentinel: N_pad, the first key past the padding, whose
+    # row lies past the table.  It must be out of bounds: mode="drop"
+    # only drops genuinely out-of-bounds scatters.  Gathers at it are
+    # clamped into the padding and their values only feed dropped writes.
+    none = cache.expiry_ms.size
     valid = mask & ~is_write
 
     # --- writes: version bump + hazard update (+ lease invalidation) -----
-    # sentinel must be OOB (N): negative indices wrap in JAX; mode="drop"
-    # only drops genuinely out-of-bounds scatters.
     w = is_write & mask
-    wk = jnp.where(w, keys, N)
-    wk_safe = jnp.minimum(wk, N - 1)
-    gv = cache.global_version.at[wk].add(1, mode="drop")
+    wk = jnp.where(w, keys, none)
+    wi = key_index(wk)
+    wi_safe = key_index(jnp.minimum(wk, none - 1))
+    gv = cache.global_version.at[wi].add(1, mode="drop")
     if mode == "ttl_per_key":
-        dt = jnp.maximum(now_ms - cache.last_write_ms[wk_safe], 1.0)
-        seen = cache.last_write_ms[wk_safe] >= 0.0
-        decayed = (1.0 - BETA) * cache.key_hazard[wk_safe] + BETA / dt
+        dt = jnp.maximum(now_ms - cache.last_write_ms[wi_safe], 1.0)
+        seen = cache.last_write_ms[wi_safe] >= 0.0
+        decayed = (1.0 - BETA) * cache.key_hazard[wi_safe] + BETA / dt
         upd = jnp.where(seen, decayed, 1.0 / jnp.maximum(dt, 1.0))
-        key_hazard = cache.key_hazard.at[wk].set(upd, mode="drop")
-        last_write = cache.last_write_ms.at[wk].set(now_ms, mode="drop")
+        key_hazard = cache.key_hazard.at[wi].set(upd, mode="drop")
+        last_write = cache.last_write_ms.at[wi].set(now_ms, mode="drop")
     else:
         # the per-key hazard log feeds only the ttl_per_key horizon;
-        # lease / ttl_aggregate leave both (N,) tables untouched — two
+        # lease / ttl_aggregate leave both tables untouched — two
         # fewer full-table scatters on every tick of the hot path
         key_hazard = cache.key_hazard
         last_write = cache.last_write_ms
     expiry = cache.expiry_ms
     if mode == "lease":
         # immediate invalidation at the (converged) proxy table
-        expiry = expiry.at[wk].set(0.0, mode="drop")
+        expiry = expiry.at[wi].set(0.0, mode="drop")
         inv_k = wk
     else:
-        inv_k = jnp.full_like(wk, N)  # TTL modes: expiry-only, no events
+        inv_k = jnp.full_like(wk, none)  # TTL modes: expiry-only, no events
 
     # --- misses install the entry with the mode's validity horizon -------
     # ... unless the write-pressure guard trips: serve-through, no install
@@ -213,8 +258,9 @@ def apply_batch(
     if avail is not None:
         bypass = bypass | (avail < AVAIL_FULL)
     install = miss & ~bypass
-    mk = jnp.where(install, keys, N)
-    mk_safe = jnp.minimum(mk, N - 1)
+    mk = jnp.where(install, keys, none)
+    mi = key_index(mk)
+    mi_safe = key_index(jnp.minimum(mk, none - 1))
     if mode == "lease":
         ttl_k = jnp.full(keys.shape, lease_ms, jnp.float32)
     elif mode == "ttl_aggregate":
@@ -223,11 +269,11 @@ def apply_batch(
         # hierarchical: per-key hazard when observed, class hazard as the
         # conservative prior for keys with no write history yet ("TTLs
         # err on freshness", §IV-C).
-        h = jnp.maximum(key_hazard[mk_safe], jnp.maximum(cache.hazard, 1e-9))
+        h = jnp.maximum(key_hazard[mi_safe], jnp.maximum(cache.hazard, 1e-9))
         ttl_k = -jnp.log1p(-p_star) / h
         ttl_k = jnp.clip(ttl_k, rtt_ms, TTL_CAP_MS)
-    expiry = expiry.at[mk].set(now_ms + ttl_k, mode="drop")
-    cached_v = cache.cached_version.at[mk].set(gv[mk_safe], mode="drop")
+    expiry = expiry.at[mi].set(now_ms + ttl_k, mode="drop")
+    cached_v = cache.cached_version.at[mi].set(gv[mi_safe], mode="drop")
 
     new = cache._replace(
         expiry_ms=expiry,
@@ -270,10 +316,11 @@ def lookup_batch(
     (new_cache, served_locally: (R,) bool).
     """
     assert mode in MODES, mode
+    ki = key_index(keys)
     _, hit, stale = classify(
-        cache.expiry_ms[keys],
-        cache.cached_version[keys],
-        cache.global_version[keys],
+        cache.expiry_ms[ki],
+        cache.cached_version[ki],
+        cache.global_version[ki],
         mask,
         is_write,
         now_ms,
@@ -306,9 +353,10 @@ def remap_invalidate(
     expiry is zeroed (never-live) and the next read revalidates at the
     new owner.  Entries whose owner did not move are untouched
     (consistent-hashing minimal disruption carries over to the cache).
+    The padding of the tiled table is never moved.
     """
     return cache._replace(
-        expiry_ms=jnp.where(moved, 0.0, cache.expiry_ms)
+        expiry_ms=jnp.where(to_table(moved, False), 0.0, cache.expiry_ms)
     )
 
 
@@ -329,6 +377,7 @@ def slow_update(
     can never push a TTL below one RTT.  The default (1.0) is exact
     identity.
     """
+    # the padding holds version -1, so it never counts as cached
     n_cached = jnp.maximum(jnp.sum(cache.cached_version >= 0), 1)
     rate = cache.win_writes / n_cached / window_ms  # invalidations/entry/ms
     hazard = (1.0 - BETA) * cache.hazard + BETA * rate

@@ -22,8 +22,12 @@ exactly), the fleet keeps
                       or invalidation happened, and which proxy
                       originated it;
   * ``lag_expiry`` / ``lag_version``
-                    — a (D, N) ring buffer of converged-table snapshots,
+                    — a ring buffer of D converged-table snapshots,
                       D = ceil(gossip_ms / dt_ms) ticks deep.
+
+Every per-key table (and each ring slot) is lane-tiled like the
+converged table's (:func:`repro.core.cache.table_shape`) and indexed
+through :func:`repro.core.cache.key_index`.
 
 Proxy p's view of key k is the *fresh* converged entry iff p originated
 the last event on k or that event is at least ``gossip_ms`` old;
@@ -63,10 +67,11 @@ class FleetState(NamedTuple):
 
     shared: cache_lib.CacheState  # converged table + aggregate counters
     tick: jnp.ndarray             # () int32 fleet-local tick counter
-    last_event_ms: jnp.ndarray    # (N,) float32 time of last gossip event
-    last_origin: jnp.ndarray      # (N,) int32 proxy that originated it
-    lag_expiry: jnp.ndarray       # (D, N) float32 snapshot ring buffer
-    lag_version: jnp.ndarray      # (D, N) int32 snapshot ring buffer
+    # per-key tables lane-tiled (cache_lib.table_shape(N)), rings (D, *that)
+    last_event_ms: jnp.ndarray    # float32 time of last gossip event
+    last_origin: jnp.ndarray      # int32 proxy that originated it
+    lag_expiry: jnp.ndarray       # (D, ...) float32 snapshot ring buffer
+    lag_version: jnp.ndarray      # (D, ...) int32 snapshot ring buffer
     hits_p: jnp.ndarray           # (P,) int32 per-proxy hits
     misses_p: jnp.ndarray         # (P,) int32 per-proxy misses
     stale_p: jnp.ndarray          # (P,) int32 per-proxy stale serves
@@ -133,15 +138,16 @@ def init_fleet(
     if D <= 0:
         raise ValueError(f"fleet needs D >= 1 ring-buffer slots, got {D}")
     zp = jnp.zeros((P,), jnp.int32)
+    shape = cache_lib.table_shape(N)
     return FleetState(
         shared=cache_lib.init_cache(N, ttl_init_ms),
         tick=jnp.zeros((), jnp.int32),
         # -inf-like sentinel: "no event yet" is always propagation-old
-        last_event_ms=jnp.full((N,), -1e30, jnp.float32),
-        last_origin=jnp.full((N,), -1, jnp.int32),
+        last_event_ms=jnp.full(shape, -1e30, jnp.float32),
+        last_origin=jnp.full(shape, -1, jnp.int32),
         # empty-cache snapshots: expiry 0 / version -1 == never live
-        lag_expiry=jnp.zeros((D, N), jnp.float32),
-        lag_version=jnp.full((D, N), -1, jnp.int32),
+        lag_expiry=jnp.zeros((D,) + shape, jnp.float32),
+        lag_version=jnp.full((D,) + shape, -1, jnp.int32),
         hits_p=zp,
         misses_p=zp,
         stale_p=zp,
@@ -189,16 +195,17 @@ def lookup_fleet(
     slot = state.tick % D  # ring slot holding the snapshot from D ticks ago
     lag_exp = state.lag_expiry[slot]
     lag_ver = state.lag_version[slot]
-    own = state.last_origin[keys] == proxy
-    propagated = now_ms - state.last_event_ms[keys] >= gossip_ms
+    ki = cache_lib.key_index(keys)
+    own = state.last_origin[ki] == proxy
+    propagated = now_ms - state.last_event_ms[ki] >= gossip_ms
     if partitioned is not None:
         propagated = propagated & ~partitioned[proxy]
     fresh = own | propagated
-    exp_view = jnp.where(fresh, sh.expiry_ms[keys], lag_exp[keys])
-    ver_view = jnp.where(fresh, sh.cached_version[keys], lag_ver[keys])
+    exp_view = jnp.where(fresh, sh.expiry_ms[ki], lag_exp[ki])
+    ver_view = jnp.where(fresh, sh.cached_version[ki], lag_ver[ki])
 
     _, hit, stale = cache_lib.classify(
-        exp_view, ver_view, sh.global_version[keys], mask, is_write, now_ms
+        exp_view, ver_view, sh.global_version[ki], mask, is_write, now_ms
     )
 
     # the per-key table writes (``scatter``) and the snapshot push
@@ -223,10 +230,12 @@ def lookup_fleet(
 
         # --- gossip log: invalidations first, installs win on collision --
         # (same intra-tick order as apply_batch's table scatters)
-        lev = state.last_event_ms.at[eff.inv_keys].set(now_ms, mode="drop")
-        lor = state.last_origin.at[eff.inv_keys].set(proxy, mode="drop")
-        lev = lev.at[eff.ins_keys].set(now_ms, mode="drop")
-        lor = lor.at[eff.ins_keys].set(proxy, mode="drop")
+        inv = cache_lib.key_index(eff.inv_keys)
+        ins = cache_lib.key_index(eff.ins_keys)
+        lev = state.last_event_ms.at[inv].set(now_ms, mode="drop")
+        lor = state.last_origin.at[inv].set(proxy, mode="drop")
+        lev = lev.at[ins].set(now_ms, mode="drop")
+        lor = lor.at[ins].set(proxy, mode="drop")
 
     # --- push the post-tick snapshot; this slot is re-read at tick+D -----
     with jax.named_scope("snapshot"):
@@ -266,7 +275,9 @@ def remap_invalidate(
     proxy's gossip freshness test selects, the entry is never-live."""
     return state._replace(
         shared=cache_lib.remap_invalidate(state.shared, moved),
-        lag_expiry=jnp.where(moved[None, :], 0.0, state.lag_expiry),
+        lag_expiry=jnp.where(
+            cache_lib.to_table(moved, False), 0.0, state.lag_expiry
+        ),
     )
 
 

@@ -1,7 +1,10 @@
-"""Cooperative cache: coherence invariants per mode."""
+"""Cooperative cache: coherence invariants per mode, and the lane-tiled
+tables against the logical ``(N,)`` model."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+import logical_cache as logical
 from repro.core import cache as cache_lib
 
 
@@ -57,13 +60,13 @@ def test_ttl_per_key_hot_keys_get_short_ttls():
         c, _ = cache_lib.lookup_batch(c, keys, mask, jnp.ones(1, bool),
                                       jnp.asarray(now), mode="ttl_per_key")
         now += 10.0
-    h_hot = float(c.key_hazard[1])
+    h_hot = float(cache_lib.table_view(c.key_hazard, 16)[1])
     assert h_hot > 0.01               # ~1/10ms
     # installing hot key now gets TTL near the floor
     keys, mask, w = _req([1])
     c, _ = cache_lib.lookup_batch(c, keys, mask, w, jnp.asarray(now),
                                   mode="ttl_per_key", rtt_ms=2.0)
-    ttl_installed = float(c.expiry_ms[1]) - now
+    ttl_installed = float(cache_lib.table_view(c.expiry_ms, 16)[1]) - now
     assert ttl_installed <= 2.0 + 1e-3   # clipped to RTT floor
 
 
@@ -75,10 +78,12 @@ def test_sentinel_does_not_corrupt_last_key():
     mask = jnp.asarray([False])        # nothing valid
     c2, hit = cache_lib.lookup_batch(c, keys, mask, jnp.zeros(1, bool),
                                      jnp.asarray(0.0), mode="lease")
-    np.testing.assert_array_equal(np.asarray(c2.expiry_ms),
-                                  np.asarray(c.expiry_ms))
-    np.testing.assert_array_equal(np.asarray(c2.global_version),
-                                  np.asarray(c.global_version))
+    np.testing.assert_array_equal(
+        np.asarray(cache_lib.table_view(c2.expiry_ms, N)),
+        np.asarray(cache_lib.table_view(c.expiry_ms, N)))
+    np.testing.assert_array_equal(
+        np.asarray(cache_lib.table_view(c2.global_version, N)),
+        np.asarray(cache_lib.table_view(c.global_version, N)))
     assert not bool(hit[0])
 
 
@@ -105,3 +110,89 @@ def test_slow_update_gamma_shrink_under_heavy_writes():
     # same hazard-free comparison isn't exact; check the γ path triggered
     assert float(hi.write_frac) > cache_lib.W_HIGH
     assert float(lo.write_frac) < cache_lib.W_HIGH
+
+
+# ---------------------------------------------------------------------------
+# Lane-tiled tables: bit for bit the logical (N,) model
+# ---------------------------------------------------------------------------
+
+
+def _traffic(rng, N, R=64):
+    """One tick: half the slots on a 16-key hot set (same-key collisions
+    inside a tick), the rest spread to the table's last keys."""
+    hot = rng.integers(0, 16, R)
+    spread = rng.integers(max(N - 256, 0), N, R)
+    keys = np.where(rng.random(R) < 0.5, hot, spread).astype(np.int32)
+    mask = rng.random(R) < 0.9
+    writes = rng.random(R) < 0.3
+    return jnp.asarray(keys), jnp.asarray(mask), jnp.asarray(writes)
+
+
+@pytest.mark.parametrize("N", [1000, 4097])
+@pytest.mark.parametrize("mode", cache_lib.MODES)
+def test_tiled_tables_match_the_logical_model(mode, N):
+    """Hits, tables and the slow loop's retune read the same as on plain
+    (N,) tables, for N that is no multiple of the 1024-key tile."""
+    rng = np.random.default_rng(N)
+    fresh = c = cache_lib.init_cache(N)
+    t = logical.init_tables(N)
+    for tick in range(40):
+        keys, mask, w = _traffic(rng, N)
+        now = jnp.asarray(tick * 50.0, jnp.float32)
+        t, want = logical.lookup(t, c, keys, mask, w, now, mode)
+        c, hit = cache_lib.lookup_batch(c, keys, mask, w, now, mode=mode)
+        np.testing.assert_array_equal(np.asarray(hit), np.asarray(want))
+        if tick % 10 == 9:
+            # n_cached counts the logical table's entries only
+            ref = cache_lib.slow_update(
+                c._replace(cached_version=t.cached_version), 500.0, 2.0)
+            c = cache_lib.slow_update(c, 500.0, 2.0)
+            assert float(c.hazard) == float(ref.hazard)
+            assert float(c.ttl_ms) == float(ref.ttl_ms)
+    assert int(c.hits) > 0 and int(c.misses) > 0
+    logical.assert_matches(c, fresh, t, N)
+
+
+@pytest.mark.parametrize("mode", cache_lib.MODES)
+def test_sentinel_writes_leave_the_padding_untouched(mode):
+    """Every "no event" slot goes to the row past the padded table, so a
+    tick of masked-out requests and writes to the last key writes no
+    padding, and the slow loop counts as many cached entries as before."""
+    N = 1000
+    fresh = cache_lib.init_cache(N)
+    keys = jnp.asarray([N - 1, N - 1, 0, 5], jnp.int32)
+    mask = jnp.asarray([True, True, False, False])
+    c, _ = cache_lib.lookup_batch(fresh, keys, mask,
+                                  jnp.asarray([False, True, True, False]),
+                                  jnp.asarray(10.0), mode=mode)
+    t, _ = logical.lookup(logical.init_tables(N), fresh, keys, mask,
+                          jnp.asarray([False, True, True, False]),
+                          jnp.asarray(10.0), mode)
+    logical.assert_matches(c, fresh, t, N)
+    assert int(jnp.sum(c.cached_version >= 0)) == 1  # key N-1 alone
+    ref = cache_lib.slow_update(
+        c._replace(cached_version=t.cached_version), 500.0, 2.0)
+    assert float(cache_lib.slow_update(c, 500.0, 2.0).hazard) == float(
+        ref.hazard)
+
+
+@pytest.mark.parametrize("N", [1000, 4097])
+def test_remap_invalidate_drops_the_same_entries(N):
+    """The fault layer's (N,) moved mask is padded with False: the same
+    entries are dropped and the padding is left alone."""
+    rng = np.random.default_rng(7)
+    fresh = cache_lib.init_cache(N)
+    expiry = jnp.asarray(rng.uniform(1.0, 1e6, N), jnp.float32)
+    c = fresh._replace(expiry_ms=cache_lib.to_table(expiry, 0.0))
+    t = logical.init_tables(N)._replace(expiry_ms=expiry)
+    moved = jnp.asarray(rng.random(N) < 0.3)
+    logical.assert_matches(cache_lib.remap_invalidate(c, moved), fresh,
+                           logical.remap(t, moved), N)
+
+
+def test_table_layout_is_whole_tiles():
+    for N in (1, 1000, 1024, 4096, 4097, 1_000_000):
+        rows, lanes = cache_lib.table_shape(N)
+        assert lanes == 128 and rows % 8 == 0
+        assert rows * lanes >= N > (rows - 8) * lanes
+    assert cache_lib.table_shape(1_000_000) == (7816, 128)

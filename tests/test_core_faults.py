@@ -240,8 +240,8 @@ def test_remap_invalidate_shared_cache():
     N = 64
     c = cache_lib.init_cache(N)
     c = c._replace(
-        expiry_ms=jnp.full((N,), 1e9, jnp.float32),
-        cached_version=jnp.zeros((N,), jnp.int32),
+        expiry_ms=cache_lib.to_table(jnp.full((N,), 1e9, jnp.float32), 0.0),
+        cached_version=cache_lib.to_table(jnp.zeros((N,), jnp.int32), -1),
     )
     moved = jnp.arange(N) % 3 == 0
     c = cache_lib.remap_invalidate(c, moved)
@@ -274,10 +274,13 @@ def test_remap_invalidate_fleet_property(P):
         # every view (converged + all snapshots) holds live entries
         fs = fs._replace(
             shared=fs.shared._replace(
-                expiry_ms=jnp.full((N,), 1e9, jnp.float32),
-                cached_version=jnp.zeros((N,), jnp.int32),
+                expiry_ms=cache_lib.to_table(
+                    jnp.full((N,), 1e9, jnp.float32), 0.0),
+                cached_version=cache_lib.to_table(
+                    jnp.zeros((N,), jnp.int32), -1),
             ),
-            lag_expiry=jnp.full((D, N), 1e9, jnp.float32),
+            lag_expiry=cache_lib.to_table(
+                jnp.full((D, N), 1e9, jnp.float32), 0.0),
             tick=jnp.asarray(tick, jnp.int32),
         )
         moved = jnp.asarray(moved_bits)
@@ -324,7 +327,7 @@ def test_install_guard_under_degraded_avail():
         c, keys, ones, ~ones, jnp.asarray(10.0), avail=degraded
     )
     assert int(c2.bypasses) == N  # nothing installed while degraded
-    assert (np.asarray(c2.expiry_ms) == 0.0).all()
+    assert (np.asarray(cache_lib.table_view(c2.expiry_ms, N)) == 0.0).all()
     c3, _ = cache_lib.lookup_batch(
         c, keys, ones, ~ones, jnp.asarray(10.0),
         avail=jnp.asarray(1.0, jnp.float32),

@@ -1,9 +1,11 @@
 """Proxy fleet: Δ=0 equivalence contract, gossip-delayed visibility,
-the write-pressure install guard, and eager SimConfig validation."""
+the write-pressure install guard, the lane-tiled tables against the
+logical ``(N,)`` model, and eager SimConfig validation."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import logical_cache as logical
 from repro.core import SimConfig, make_workload, simulate
 from repro.core import cache as cache_lib
 from repro.core import fleet as fleet_lib
@@ -48,10 +50,11 @@ def test_gossip_zero_fleet_matches_shared_table_in_sim(mode, P):
     assert int(sc.misses) == int(fc.misses)
     assert int(sc.stale_serves) == int(fc.stale_serves)
     assert int(sc.bypasses) == int(fc.bypasses)
-    np.testing.assert_array_equal(np.asarray(sc.expiry_ms),
-                                  np.asarray(fc.shared.expiry_ms))
-    np.testing.assert_array_equal(np.asarray(sc.global_version),
-                                  np.asarray(fc.shared.global_version))
+    N = a.config.N
+    for table in ("expiry_ms", "global_version"):
+        np.testing.assert_array_equal(
+            np.asarray(cache_lib.table_view(getattr(sc, table), N)),
+            np.asarray(cache_lib.table_view(getattr(fc.shared, table), N)))
     np.testing.assert_array_equal(a.queue_timeline, b.queue_timeline)
     np.testing.assert_array_equal(a.cache_hits, b.cache_hits)
 
@@ -127,6 +130,64 @@ def test_gossip_delay_monotonically_hurts_lease_coherence():
         stale.append(int(r.final_cache.stale_serves))
     assert stale[0] == 0                  # Δ=0 recovers the lease guarantee
     assert stale[2] > stale[1] >= stale[0]
+
+
+# ---------------------------------------------------------------------------
+# Lane-tiled tables: bit for bit the logical (N,) model
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("gossip_ms", [0.0, 100.0])
+@pytest.mark.parametrize("P", [2, 8])
+@pytest.mark.parametrize("N", [1000, 4097])
+def test_tiled_fleet_matches_the_logical_model(N, P, gossip_ms):
+    """Views, hits, the gossip log and the snapshot ring read the same
+    as on plain (N,) tables and a (D, N) ring, the padding untouched."""
+    rng = np.random.default_rng(N + P)
+    D = fleet_lib.delay_ticks(gossip_ms, DT)
+    fresh = fl = fleet_lib.init_fleet(N, P, D)
+    f = logical.init_fleet_tables(N, D)
+    R = 64
+    for tick in range(30):
+        hot = rng.integers(0, 16, R)
+        spread = rng.integers(N - 256, N, R)
+        keys = jnp.asarray(np.where(rng.random(R) < 0.5, hot, spread),
+                           jnp.int32)
+        mask = jnp.asarray(rng.random(R) < 0.9)
+        w = jnp.asarray(rng.random(R) < 0.3)
+        proxy = fleet_lib.proxy_assign(R, P, fl.tick)
+        now = jnp.asarray(tick * DT, jnp.float32)
+        f, want = logical.lookup_fleet(f, fl, keys, mask, w, proxy, now,
+                                       "lease", gossip_ms)
+        fl, hit = fleet_lib.lookup_fleet(fl, keys, mask, w, proxy, now,
+                                         gossip_ms=gossip_ms)
+        np.testing.assert_array_equal(np.asarray(hit), np.asarray(want))
+    assert int(fl.hits) > 0
+    logical.assert_matches(fl, fresh, f, N)
+
+
+@pytest.mark.parametrize("N", [1000, 4097])
+def test_fleet_remap_invalidate_drops_the_same_entries(N):
+    """The padded moved mask drops the same entries from the converged
+    table and from every ring slot, and no padding."""
+    rng = np.random.default_rng(11)
+    D = 3
+    fresh = fleet_lib.init_fleet(N, 2, D)
+    expiry = jnp.asarray(rng.uniform(1.0, 1e6, N), jnp.float32)
+    lag = jnp.asarray(rng.uniform(1.0, 1e6, (D, N)), jnp.float32)
+    fl = fresh._replace(
+        shared=fresh.shared._replace(
+            expiry_ms=cache_lib.to_table(expiry, 0.0)),
+        lag_expiry=cache_lib.to_table(lag, 0.0),
+    )
+    f = logical.init_fleet_tables(N, D)
+    f = f._replace(shared=f.shared._replace(expiry_ms=expiry),
+                   lag_expiry=lag)
+    moved = jnp.asarray(rng.random(N) < 0.3)
+    f = f._replace(shared=logical.remap(f.shared, moved),
+                   lag_expiry=jnp.where(moved, 0.0, f.lag_expiry))
+    logical.assert_matches(fleet_lib.remap_invalidate(fl, moved), fresh, f,
+                           N)
 
 
 # ---------------------------------------------------------------------------

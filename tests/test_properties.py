@@ -196,8 +196,8 @@ def test_fleet_gossip_zero_matches_shared_table(ops, P, mode):
     for field in ("expiry_ms", "cached_version", "global_version",
                   "key_hazard"):
         np.testing.assert_array_equal(
-            np.asarray(getattr(shared, field)),
-            np.asarray(getattr(fl.shared, field)))
+            np.asarray(cache_lib.table_view(getattr(shared, field), N)),
+            np.asarray(cache_lib.table_view(getattr(fl.shared, field), N)))
 
 
 @given(writes=st.lists(st.floats(1.0, 1000.0), min_size=2, max_size=30))

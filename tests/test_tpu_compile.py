@@ -1,4 +1,5 @@
-"""Compile the midas_route kernels for a described TPU v5e, with no chip.
+"""Compile the midas_route kernels, and the fleet cache's batched table
+updates, for a described TPU v5e, with no chip.
 
 The interpret-mode parity tests in ``test_kernels.py`` cannot see what
 only the chip's compiler refuses (unsupported reductions, vector
@@ -12,7 +13,9 @@ only one process at a time may load the TPU library, and every test
 worker imports this file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -146,3 +149,83 @@ def test_the_engine_names_the_routing_kernel_in_its_routing_phase(
     assert kernel, calls
     pmap = obs_trace.parse_phases(text)
     assert {pmap.get(c) for c in kernel} == {("tick/route", "")}
+
+
+
+def _arrays(text, opcode):
+    """Per instruction of ``opcode``: (element count, rank) of every
+    array in its result shape (a while's shape is its carried tuple)."""
+    return [
+        [(math.prod(int(d) for d in dims.split(",") if d), dims.count(",") + 1)
+         for dims in re.findall(r"\b(?:pred|[fsu]\d+)\[([\d,]*)\]", shape)]
+        for shape, op in re.findall(
+            r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", text, re.M)
+        if op == opcode
+    ]
+
+
+def _grid_scan(cell):
+    """A scan over ticks of ``cell`` vmapped over the grid axis, as the
+    sweep engine runs a middleware stage."""
+
+    def run(state, keys, mask, writes):
+        def tick(s, x):
+            s, hit = jax.vmap(cell)(s, *x)
+            return s, hit.sum()
+
+        return jax.lax.scan(tick, state, (keys, mask, writes))
+
+    return run
+
+
+@pytest.mark.parametrize("stage", ["fleet_cache", "cache"])
+def test_batched_table_scatters_compile_in_place(one_chip, stage):
+    """The per-key tables under the sweep's grid vmap.  A ``(G, N)``
+    carry makes the TPU compiler copy the whole batch into a linear
+    ``(G*N,)`` buffer and back around every scatter, in loops of
+    ``dynamic-update-slice``s.  The lane-tiled tables already have that
+    buffer's layout, so the scan compiles with no such loop."""
+    from repro.core import cache as cache_lib
+    from repro.core import fleet as fleet_lib
+
+    G, N, R, P, T = 8, 1_000_000, 512, 128, 4
+    D = fleet_lib.delay_ticks(100.0, 50.0)
+    if stage == "fleet_cache":
+        def cell(st, keys, mask, writes):
+            proxy = fleet_lib.proxy_assign(R, P, st.tick)
+            now = st.tick.astype(jnp.float32) * 50.0
+            return fleet_lib.lookup_fleet(st, keys, mask, writes, proxy,
+                                          now, mode="lease", gossip_ms=100.0)
+
+        init = fleet_lib.init_fleet(N, P, D)
+    else:
+        def cell(st, keys, mask, writes):
+            return cache_lib.lookup_batch(st, keys, mask, writes,
+                                          jnp.asarray(1.0))
+
+        init = cache_lib.init_cache(N)
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((G,) + x.shape, x.dtype,
+                                       sharding=one_chip),
+        init,
+    )
+    xs = [jax.ShapeDtypeStruct((T, G, R), dt, sharding=one_chip)
+          for dt in (jnp.int32, jnp.bool_, jnp.bool_)]
+    text = _compile_text(_grid_scan(cell), state, *xs)
+
+    rows, lanes = cache_lib.table_shape(N)
+    table = G * rows * lanes  # one grid-batched table, padding included
+    whiles = _arrays(text, "while")
+    # the scatter itself may view a tiled table as its (G*N_pad,) linear
+    # buffer (a bitcast); no loop may carry such a buffer to copy into
+    assert not [w for w in whiles
+                if {(G * N, 1), (table, 1)} & set(w)], whiles
+    if stage == "cache":
+        # the scan's own loop is the only one; no slice copies a table
+        assert len(whiles) == 1, whiles
+        assert not [a for a in _arrays(text, "dynamic-update-slice")
+                    if max(n for n, _ in a) >= G * N]
+    else:
+        # besides the scan, only the lag ring's reads at its batched
+        # slot build loops, and each carries the (G, D, ...) ring
+        assert all(D * table in {n for n, _ in w} for w in whiles), whiles
