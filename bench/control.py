@@ -30,7 +30,7 @@ def readings(bench, workload: str, seeds, log=print):
     import jax.numpy as jnp
 
     from midasbench import cell as cell_lib
-    from midasbench import check, reference
+    from midasbench import check
     from repro.core import run_sweep
     from run import sample_coords
 
@@ -42,12 +42,12 @@ def readings(bench, workload: str, seeds, log=print):
         rows = cell_lib.rows_of(cell, run_sweep(cell.spec))
         t1 = time.perf_counter()
         cell.spec = None
-        targets = cell_lib.reference_targets(cell)
+        targets = cell.targets()
         sound, control = [], []
         for w, s in sample_coords(cell, seed, k):
             grid = cell.grids[w]
-            ref32 = reference.simulate(cell.dep, grid, s, targets)
-            ref16 = reference.simulate(
+            ref32 = cell.ref.simulate(cell.dep, grid, s, targets)
+            ref16 = cell.ref.simulate(
                 cell.dep, grid, s, targets, dtype=jnp.bfloat16
             )
             sound.append(check.row_gap(rows[(w, s)], ref32))

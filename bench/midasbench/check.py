@@ -1,10 +1,11 @@
 """The numbers that decide ``correct``, each against its own limit.
 
 * ``row_gap``: over the sampled grid cells and every field of their
-  summary rows (queue sums and maximum, the CV sums, both histograms,
-  arrivals, steered, eligible, cache hits, and the per-tick knob and
-  mean-queue trajectories), the widest relative L1 gap between the
-  program's row and the plain reference's:
+  summary rows (the fields the cell's reference compares: in
+  ``midas_queue`` the queue sums and maximum, the CV sums, both
+  histograms, arrivals, steered, eligible, cache hits, and the per-tick
+  knob and mean-queue trajectories), the widest relative L1 gap between
+  the program's row and the plain reference's:
   ``sum|program - reference| / max(sum|reference|, 1)``.
 * ``rows_differing``: rows of the window's later sweeps that are not
   bitwise equal to the first sweep's row of the same grid cell (every
@@ -17,7 +18,6 @@ from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
 
-from midasbench.reference import FIELDS
 
 Row = Dict[str, np.ndarray]
 
@@ -32,14 +32,14 @@ def field_gap(a, b) -> float:
 
 def row_gap(prog: Row, ref: Row) -> Tuple[float, str]:
     """Widest field gap of one row, and the field it is in."""
-    gaps = {f: field_gap(prog[f], ref[f]) for f in FIELDS}
+    gaps = {f: field_gap(prog[f], ref[f]) for f in ref}
     worst = max(gaps, key=gaps.get)
     return gaps[worst], worst
 
 
 def rows_equal(a: Row, b: Row) -> bool:
     return all(
-        np.array_equal(np.asarray(a[f]), np.asarray(b[f])) for f in FIELDS
+        np.array_equal(np.asarray(a[f]), np.asarray(b[f])) for f in a
     )
 
 

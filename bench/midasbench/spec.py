@@ -2,27 +2,52 @@
 
 Everything that belongs to one deployment, one traffic mix, one cell or
 one per-layer metric is a file of its own, found by the name
-``BENCHMARK.json`` gives it:
+``BENCHMARK.json`` or another of these files gives it:
 
-* ``configs/<config>.json``: the deployment (its simulator settings,
-  source, cuts, assumptions and guarantees);
+* ``configs/<config>.json``: the deployment: its simulator settings
+  under ``"sim"`` (every key goes to the program's ``SimConfig`` as it
+  is, a list as a tuple; a key ``SimConfig`` lacks fails at set-up), the
+  name of its plain reference under ``"reference"``, and its source,
+  cuts, assumptions and guarantees;
 * ``traffic/<traffic>.json``: the sweep grid a user submits (policy
-  stack, scenarios, horizon, slots, seeds per sweep, devices, targets);
+  stack, scenarios, horizon, slots, seeds per sweep, devices, targets),
+  with the name of its generator under ``"generator"``;
+* ``reference/<reference>.py``: a plain reference of the deployment's
+  semantics, importing nothing of the program.  It provides
+  ``deployment(sim, traffic)``, its view of the deployment, which raises
+  a ``ValueError`` naming every ``sim`` or traffic key and value it does
+  not implement; ``FIELDS``, pairs of (reference field, attribute of the
+  program's ``SweepResult.row``) that the rows are compared over;
+  ``targets(dep, traffic, gen)``, the control targets, with ``gen`` the
+  cell's generator module for any grid it needs (a warmup's); and
+  ``simulate(dep, grid, seed, targets, dtype=jnp.float32)``, one grid
+  cell's row as ``{field: float64 array}``, ``dtype`` the precision
+  of its floats (a lower one is the control that ``correct`` rejects);
+* ``traffic_gen/<generator>.py``: a traffic generator with
+  ``make(scenario, *, T, m, seed, N, R, dt_ms, service_ms)`` returning a
+  ``NamedTuple`` of ``(T, R)`` arrays from the seed; its fields go to the
+  program's ``repro.core.workloads.Workload`` by name, and one
+  ``Workload`` lacks fails at set-up;
 * ``limits/<workload>.json``: the numbers that decide ``correct`` in
-  that cell, each with its limit;
+  that cell, each with its limit, and ``sample_cells``, the grid cells
+  the reference simulates (on several devices, scenarios × devices of
+  them read every device's seeds in every scenario);
 * ``metrics/<metric>.py``: a reader with ``read(ctx)`` that returns the
   metric or ``None`` when it finds nothing to read;
 * ``cost/<kernel>.py``: the operations and bytes of one kernel call.
 
-Adding a cell, a mix, a deployment or a metric is adding such files and
-an entry in ``BENCHMARK.json``; no file of the harness changes.
+Adding a cell, a mix, a deployment with its own semantics and traffic,
+or a metric is adding such files and an entry in ``BENCHMARK.json``;
+no file of the harness changes.
 """
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
 from types import ModuleType
 from typing import Any, Dict
@@ -62,6 +87,7 @@ class Bench:
     def __init__(self, benchmark: Dict[str, Any], bench_dir: Path):
         self.doc = benchmark
         self.dir = Path(bench_dir)
+        self._modules: Dict[Path, ModuleType] = {}
         for c in benchmark["configs"]:
             check_name(c["name"], "config")
         for w in benchmark["workloads"]:
@@ -102,11 +128,25 @@ class Bench:
     def limits(self, workload: str) -> Dict[str, Any]:
         return load_json(self._file("limits", workload, ".json"))
 
+    def _module(self, sub: str, name: str) -> ModuleType:
+        """The module ``<sub>/<name>.py``, loaded once per ``Bench`` (a
+        reference's jitted functions then compile once)."""
+        path = self._file(sub, name, ".py")
+        if path not in self._modules:
+            self._modules[path] = _load_module(path)
+        return self._modules[path]
+
     def reader(self, metric: str) -> ModuleType:
-        return _load_module(self._file("metrics", metric, ".py"))
+        return self._module("metrics", metric)
 
     def cost(self, kernel: str) -> ModuleType:
-        return _load_module(self._file("cost", kernel, ".py"))
+        return self._module("cost", kernel)
+
+    def reference(self, name: str) -> ModuleType:
+        return self._module("reference", name)
+
+    def generator(self, name: str) -> ModuleType:
+        return self._module("traffic_gen", name)
 
     def metrics_for(self, kind: str, workload: str):
         """The ``kind`` metrics that ``workload`` reports."""
@@ -118,9 +158,16 @@ class Bench:
 
 
 def _load_module(path: Path) -> ModuleType:
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{path.parent.name}_{path.stem}", path
-    )
+    """The module at ``path``, registered in ``sys.modules`` (dataclasses
+    look their module up there) under a name unique to its path."""
+    tag = hashlib.sha1(str(path).encode()).hexdigest()[:12]
+    name = f"bench_{path.parent.name}_{path.stem}_{tag}"
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
     return mod

@@ -1,4 +1,5 @@
-"""Plain reference of the MIDAS queue-network simulator.
+"""Plain reference of the MIDAS queue-network simulator: the reference
+module ``midas_queue`` (a configuration names it under ``"reference"``).
 
 An implementation of the simulator's semantics written from the paper's
 algorithm and the deployment's stated settings, independent of the code
@@ -64,8 +65,16 @@ HIST_EDGES = np.geomspace(1e-2, 1e6, 513)
 WARMUP_T = 1200
 WARMUP_SEED = 99
 
+# the configuration's settings this reference takes as they are stated
+SIM_KEYS = (
+    "m", "P", "N", "V", "dt_ms", "service_ms", "d_max", "rtt_ms",
+    "n_groups", "lease_ms", "gossip_ms", "fleet_routing",
+)
+# settings it implements one value of
+FIXED = {"cache_mode": "lease", "consensus": "mean"}
 POLICIES = ("hash", "power_of_d", "midas")
 MIDDLEWARE = ((), ("cache",), ("fleet_cache",))
+CONTROLLERS = ("hysteresis",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,14 +97,6 @@ class Deployment:
     policy: str
     middleware: Tuple[str, ...]
 
-    def __post_init__(self):
-        if self.policy not in POLICIES:
-            raise ValueError(f"reference has no policy {self.policy!r}")
-        if tuple(self.middleware) not in MIDDLEWARE:
-            raise ValueError(
-                f"reference has no middleware {self.middleware!r}"
-            )
-
     @property
     def fast_ticks(self) -> int:
         return max(int(round(T_FAST_MS / self.dt_ms)), 1)
@@ -115,6 +116,37 @@ class Deployment:
     @property
     def waves(self) -> int:
         return self.P if self.fleet_routing else self.n_groups
+
+
+def deployment(sim: Dict, traffic: Dict) -> Deployment:
+    """The deployment a configuration's ``sim`` settings and a traffic
+    file's policy stack state; a ``ValueError`` names every key and
+    value this reference does not implement."""
+    bad = [f"sim.{k} (missing)" for k in SIM_KEYS if k not in sim]
+    for k, v in sim.items():
+        if k in FIXED and v != FIXED[k]:
+            bad.append(f"sim.{k}={v!r} (only {FIXED[k]!r})")
+        elif k not in FIXED and k not in SIM_KEYS:
+            bad.append(f"sim.{k}={v!r}")
+    policy = traffic["policy"]
+    middleware = tuple(traffic["middleware"])
+    controller = traffic.get("controller", "hysteresis")
+    if policy not in POLICIES:
+        bad.append(f"policy={policy!r}")
+    if middleware not in MIDDLEWARE:
+        bad.append(f"middleware={list(middleware)!r}")
+    if controller not in CONTROLLERS:
+        bad.append(f"controller={controller!r}")
+    if bad:
+        raise ValueError(
+            "reference midas_queue does not implement " + ", ".join(bad)
+        )
+    return Deployment(
+        **{k: sim[k] for k in SIM_KEYS},
+        fixed_d=int(traffic.get("fixed_d", 2)),
+        policy=policy,
+        middleware=middleware,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -675,10 +707,46 @@ def warmup_targets(dep: Deployment, light_grid) -> Tuple[float, float]:
     )
 
 
+def targets(dep: Deployment, traffic: Dict, gen) -> Tuple[float, float]:
+    """The control targets: pinned by the traffic file, or, for the
+    adaptive policy with the warmup on, the warmup's on light traffic
+    from the cell's generator ``gen`` at the width the program's warmup
+    takes (4 x capacity + 8 slots), else the defaults."""
+    pinned = traffic.get("targets")
+    if pinned is not None:
+        return tuple(map(float, pinned))
+    if traffic.get("warmup") and dep.policy == "midas":
+        light = gen.make(
+            "light",
+            T=WARMUP_T,
+            m=dep.m,
+            seed=WARMUP_SEED,
+            N=dep.N,
+            R=int(4 * dep.m * dep.dt_ms / dep.service_ms) + 8,
+            dt_ms=dep.dt_ms,
+            service_ms=dep.service_ms,
+        )
+        return warmup_targets(dep, light)
+    return 0.15, 5.0 * dep.service_ms
+
+
+# (reference field, the program's SweepResult.row attribute)
 FIELDS = (
-    "queue_sum", "queue_max", "cv_sum", "cv_count", "queue_hist",
-    "lat_hist", "arrivals", "steered", "eligible", "cache_hits",
-    "d", "delta_l", "f_max", "pressure", "q_mean",
+    ("queue_sum", "queue_sum"),
+    ("queue_max", "queue_max_v"),
+    ("cv_sum", "cv_sum"),
+    ("cv_count", "cv_count"),
+    ("queue_hist", "queue_hist"),
+    ("lat_hist", "lat_hist"),
+    ("arrivals", "arrivals_total"),
+    ("steered", "steered_total"),
+    ("eligible", "eligible_total"),
+    ("cache_hits", "cache_hits_total"),
+    ("d", "d_timeline"),
+    ("delta_l", "delta_l_timeline"),
+    ("f_max", "f_max_timeline"),
+    ("pressure", "pressure"),
+    ("q_mean", "q_mean_timeline"),
 )
 
 
@@ -690,7 +758,7 @@ def simulate(
     dtype=jnp.float32,
 ) -> Dict[str, np.ndarray]:
     """The summary row of one (grid, seed) cell: every field as float64
-    numpy, keyed as :data:`FIELDS`."""
+    numpy, keyed by the reference fields of :data:`FIELDS`."""
     keys, mask, is_write = (np.asarray(a) for a in grid)
     acc, ys = _run(
         dep, dtype, False, seed, jnp.asarray(targets, jnp.float32),
@@ -699,5 +767,5 @@ def simulate(
     vals = list(acc) + list(ys)
     return {
         f: np.asarray(jnp.asarray(v, jnp.float32), np.float64)
-        for f, v in zip(FIELDS, vals)
+        for (f, _), v in zip(FIELDS, vals)
     }

@@ -13,7 +13,8 @@ it instead traces one or two sweeps (``traced_sweeps`` in the traffic
 file) with the profiler and reports the
 per-layer metrics.  It then checks the window's rows: every sweep's rows
 must equal the first sweep's, and a sample of grid cells drawn from the
-seed must match the plain reference (``midasbench/reference.py``).
+seed must match the plain reference that the cell's configuration names
+(``reference/<name>.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted`` (grid cells simulated in the window), ``failed``,
@@ -96,16 +97,26 @@ class Ctx:
 
 
 def sample_coords(cell, seed: int, k: int):
-    """``k`` grid cells drawn from the seed, one scenario after another."""
+    """``k`` grid cells drawn from the seed, one scenario after another.
+
+    A sweep over several devices splits its seeds into one block per
+    device, in order, as ``shard_map`` does; each scenario's turns then
+    go to the blocks in turn, so that ``k`` of scenarios × devices reads
+    every device's results in every scenario.  On one device each seed
+    is drawn from all of them."""
     import numpy as np
 
     rng = np.random.default_rng((int(seed) % 2**64, 1))
     names = list(cell.grids)
-    return [
-        (names[i % len(names)], cell.sim_seeds[int(rng.integers(
-            len(cell.sim_seeds)))])
-        for i in range(k)
-    ]
+    seeds = cell.sim_seeds
+    per = -(-len(seeds) // cell.devices)
+    blocks = [seeds[i:i + per] for i in range(0, len(seeds), per)]
+    out = []
+    for i in range(k):
+        block = blocks[(i // len(names)) % len(blocks)]
+        out.append((names[i % len(names)], block[int(rng.integers(
+            len(block)))]))
+    return out
 
 
 def timed_window(run_sweep, spec, seconds: float):
@@ -157,7 +168,7 @@ def check_outputs(cell, seed: int, limits, first, results):
     sweep's sampled rows against the plain reference, which runs after
     the program's device state is freed."""
     from midasbench import cell as cell_lib
-    from midasbench import check, reference
+    from midasbench import check
 
     first_rows = cell_lib.rows_of(cell, first)
     later_rows = [cell_lib.rows_of(cell, r) for r in results]
@@ -165,10 +176,10 @@ def check_outputs(cell, seed: int, limits, first, results):
     del first, results
     gc.collect()
     t_ref = time.perf_counter()
-    targets = cell_lib.reference_targets(cell)
+    targets = cell.targets()
     gaps = []
     for w, s in sample_coords(cell, seed, int(limits["sample_cells"])):
-        ref_row = reference.simulate(cell.dep, cell.grids[w], s, targets)
+        ref_row = cell.ref.simulate(cell.dep, cell.grids[w], s, targets)
         gap, field = check.row_gap(later_rows[-1][(w, s)], ref_row)
         gaps.append(gap)
         log(f"reference {w} seed {s}: gap {gap!r} (widest in {field})")

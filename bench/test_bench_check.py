@@ -1,62 +1,31 @@
 """What decides ``correct``, on a cell small enough for the CPU: a sound
 run passes; the control (the reference in bfloat16) and each fault the
 cells can have, planted under the timed path, fail; a run off the TPU
-and a recompile inside the window fail outright."""
+and a recompile inside the window fail outright.  A deployment brought
+as new files only (its own reference and generator) runs end to end,
+and its own reference decides ``correct``."""
 
-import json
-import shutil
 import time
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 import run as runner
+from conftest import REAL, tiny_cell, write_bench
 from midasbench import cell as cell_lib
-from midasbench import check, reference
-from midasbench.spec import Bench
+from midasbench import check
 from repro.core import sim
 
-ROOT = Path(__file__).resolve().parent.parent
-REAL = Bench.from_root(ROOT)
 CELLS = [w["name"] for w in REAL.doc["workloads"]]
 SEED = 2**31 + 4242
 
 
-@pytest.fixture(scope="module")
-def tiny(tmp_path_factory):
-    """A copy of the testbed cell cut to 40 ticks, two scenarios and two
-    seeds per sweep, with pinned targets, and the metric readers."""
-    root = tmp_path_factory.mktemp("tiny")
-    d = root / "bench"
-    for sub in ("configs", "traffic", "limits"):
-        (d / sub).mkdir(parents=True)
-    for sub in ("metrics", "cost"):
-        shutil.copytree(REAL.dir / sub, d / sub)
-    cfg = REAL.config("paper_testbed")
-    (d / "configs" / "paper_testbed.json").write_text(json.dumps(cfg))
-    tr = REAL.traffic("midas_cache_e8")
-    tr.update(T=40, scenarios=["bursty", "flash_crowd"], seeds_per_sweep=2,
-              warmup=False, targets=[0.5, 400.0])
-    (d / "traffic" / "tiny.json").write_text(json.dumps(tr))
-    lim = REAL.limits("testbed_midas")
-    lim["sample_cells"] = 2
-    (d / "limits" / "tiny_cell.json").write_text(json.dumps(lim))
-    doc = json.loads((ROOT / "BENCHMARK.json").read_text())
-    doc["workloads"] = [{"name": "tiny_cell", "config": "paper_testbed",
-                         "traffic": "tiny", "chips": 1, "why": "test"}]
-    for mt in doc["end_to_end"] + doc["per_layer"]:
-        mt.pop("workloads", None)
-    (root / "BENCHMARK.json").write_text(json.dumps(doc))
-    return Bench(doc, d)
-
-
-def _run(bench, **kw):
+def _run(bench, workload="tiny_cell", **kw):
     jax.clear_caches()
     try:
         return runner.run(
-            bench, "tiny_cell", SEED, 0.2, False,
+            bench, workload, SEED, 0.2, False,
             t_start=time.perf_counter(), require_chip=False, **kw
         )
     finally:
@@ -88,11 +57,11 @@ def test_a_traced_run_reports_the_per_layer_metrics(tiny, tmp_path):
 def test_the_bfloat16_control_fails_each_cells_limit(tiny, workload):
     limit = REAL.limits(workload)["limits"]["row_gap"]
     cell = cell_lib.build(tiny, "tiny_cell", SEED)
-    targets = cell_lib.reference_targets(cell)
+    targets = cell.targets()
     for name, grid in cell.grids.items():
         s = cell.sim_seeds[0]
-        ref32 = reference.simulate(cell.dep, grid, s, targets)
-        ref16 = reference.simulate(
+        ref32 = cell.ref.simulate(cell.dep, grid, s, targets)
+        ref16 = cell.ref.simulate(
             cell.dep, grid, s, targets, dtype=jnp.bfloat16
         )
         assert check.row_gap(ref16, ref32)[0] > limit
@@ -164,3 +133,79 @@ def test_a_recompile_inside_the_window_fails(tiny, monkeypatch):
     monkeypatch.setattr(repro.core, "run_sweep", recompiling)
     with pytest.raises(runner.Failed, match="compiled inside the window"):
         _run(tiny)
+
+
+# a generator of its own: gen's scenarios and one more
+NEW_GEN = """
+def steady_renames(p):
+    return p.make("skewed", write_frac=0.6)
+
+
+GENERATORS["steady_renames"] = steady_renames
+"""
+# the reference's controller starts from d = 3 where the paper says 2
+WRONG = ("D_INIT, D_MIN, D_MAX = 2, 1, 4", "D_INIT, D_MIN, D_MAX = 3, 1, 4")
+
+
+@pytest.mark.parametrize("sound", [True, False], ids=["copy", "wrong_copy"])
+def test_a_deployment_of_new_files_runs_and_its_reference_decides(
+    tmp_path, sound
+):
+    queue = (REAL.dir / "reference" / "midas_queue.py").read_text()
+    if not sound:
+        assert queue.count(WRONG[0]) == 1
+        queue = queue.replace(*WRONG)
+    gen = (REAL.dir / "traffic_gen" / "gen.py").read_text() + NEW_GEN
+    cell = tiny_cell(
+        "new_cell", "paper_testbed", "midas_cache_e8", "testbed_midas",
+        sim={"N": 2048}, T=40, scenarios=["steady_renames", "bursty"],
+        seeds_per_sweep=2, warmup=False, targets=[0.5, 400.0],
+        generator="new_gen",
+    )
+    cell["config"]["reference"] = "new_queue"
+    cell["limits"]["sample_cells"] = 2
+    bench = write_bench(tmp_path, [cell], files={
+        "reference/new_queue.py": queue, "traffic_gen/new_gen.py": gen,
+    })
+    built = cell_lib.build(bench, "new_cell", SEED)
+    assert built.ref is bench.reference("new_queue")
+    assert list(built.grids) == ["steady_renames", "bursty"]
+    out = _run(bench, "new_cell")
+    assert out["correct"] is sound
+    assert (out["checks"]["row_gap"]["value"] == 0.0) is sound
+    assert out["checks"]["rows_differing"]["value"] == 0
+
+
+def _seeded_cell(devices, n_seeds, scenarios=("a", "b", "c", "d")):
+    return cell_lib.Cell(
+        name="sampled", config={}, traffic={"devices": devices}, ref=None,
+        gen=None, dep=None, grids=dict.fromkeys(scenarios),
+        sim_seeds=tuple(range(100, 100 + n_seeds)),
+    )
+
+
+@pytest.mark.parametrize("n_seeds", [8, 32])
+def test_on_one_device_the_sample_is_drawn_from_every_seed(n_seeds):
+    import numpy as np
+
+    cell = _seeded_cell(1, n_seeds)
+    rng = np.random.default_rng((SEED % 2**64, 1))
+    drawn = [
+        ("abcd"[i % 4], cell.sim_seeds[int(rng.integers(n_seeds))])
+        for i in range(6)
+    ]
+    assert runner.sample_coords(cell, SEED, 6) == drawn
+
+
+@pytest.mark.parametrize("n_seeds", [8, 32, 7])
+def test_a_sharded_sample_reads_every_devices_block_in_every_scenario(
+    n_seeds
+):
+    # the blocks shard_map gives each device (a short last one padded)
+    per = -(-n_seeds // 4)
+    block = {s: (s - 100) // per for s in range(100, 100 + n_seeds)}
+    cell = _seeded_cell(4, n_seeds)
+    got = runner.sample_coords(cell, SEED, 16)
+    assert {(w, block[s]) for w, s in got} == {
+        (w, b) for w in "abcd" for b in range(-(-n_seeds // per))
+    }

@@ -7,20 +7,20 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-import traffic_gen
 from midasbench import cell as cell_lib
 from midasbench.spec import Bench
 
 ROOT = Path(__file__).resolve().parent.parent
 BENCH = Bench.from_root(ROOT)
 WORKLOADS = [w["name"] for w in BENCH.doc["workloads"]]
+GEN = BENCH.generator("gen")
 
 
-@pytest.mark.parametrize("name", sorted(traffic_gen.GENERATORS))
+@pytest.mark.parametrize("name", sorted(GEN.GENERATORS))
 def test_one_seed_gives_the_same_grid_twice(name):
     kw = dict(T=300, m=8, N=4096, seed=2**31 + 17)
-    a, b = traffic_gen.make(name, **kw), traffic_gen.make(name, **kw)
-    other = traffic_gen.make(name, **dict(kw, seed=kw["seed"] + 1))
+    a, b = GEN.make(name, **kw), GEN.make(name, **kw)
+    other = GEN.make(name, **dict(kw, seed=kw["seed"] + 1))
     for x, y in zip(a, b):
         assert np.array_equal(np.asarray(x), np.asarray(y))
     assert not np.array_equal(np.asarray(a.keys), np.asarray(other.keys))

@@ -1,5 +1,15 @@
-"""Seeded request grids: Poisson arrivals per tick, zipf or uniform keys,
-bursts on rotating hot subsets, and the combinators that compose them.
+"""The benchmark's traffic generator ``gen`` (a traffic file names it
+under ``"generator"``): seeded request grids, Poisson arrivals per tick,
+zipf or uniform keys, bursts on rotating hot subsets, and the
+combinators that compose them.
+
+A copy of the program's generators that the cells use (``light``,
+``skewed``, ``bursty``, the four combinators, and the ``rename_storm``,
+``flash_crowd`` and ``job_startup`` scenarios), kept here so that a
+change to the program's own workload package cannot move the yardstick.
+``make(name, ...)`` returns a :class:`Grid` of ``(T, R)`` arrays: request
+keys in ``[0, N)``, a validity mask (a prefix of each row) and a write
+flag.
 
 Rates are fractions of the aggregate service capacity
 ``cap = m * dt_ms / service_ms`` requests per tick.  Everything is a pure
