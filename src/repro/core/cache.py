@@ -52,6 +52,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.faults.base import AVAIL_FULL
@@ -358,6 +359,19 @@ def remap_invalidate(
     return cache._replace(
         expiry_ms=jnp.where(to_table(moved, False), 0.0, cache.expiry_ms)
     )
+
+
+def remap_counted(
+    cache: CacheState, moved: jnp.ndarray, now_ms: jnp.ndarray
+) -> Tuple[CacheState, jnp.ndarray]:
+    """:func:`remap_invalidate`, and the () float32 count of the entries
+    it drops that were still live at ``now_ms``."""
+    live = to_table(moved, False) & (cache.expiry_ms > now_ms)
+    n = jnp.sum(live).astype(jnp.float32)
+    # the count reads the table before the remap rewrites it; the
+    # barrier orders it first, so the remap still writes in place
+    n, expiry = jax.lax.optimization_barrier((n, cache.expiry_ms))
+    return remap_invalidate(cache._replace(expiry_ms=expiry), moved), n
 
 
 def slow_update(

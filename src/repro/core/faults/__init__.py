@@ -17,6 +17,7 @@ from repro.core.faults.base import (  # noqa: F401
     apply_traffic,
     available,
     compile_faults,
+    counters,
     detect_available,
     detect_ticks,
     feasible_by_epoch,

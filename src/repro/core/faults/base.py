@@ -41,11 +41,13 @@ import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple, Type
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hashring
 from repro.core import registry as registry_lib
+from repro.obs import trace as obs_trace
 
 # Detection timeout: a member silent for longer is presumed FAILED (the
 # host-side reference is repro.ft.failures.FailureDetector).
@@ -140,7 +142,10 @@ def get(kind: str) -> FaultSpec:
 
 
 def normalize(faults) -> Tuple[FaultEvent, ...]:
-    """Canonical event tuple: names become default-parameter events.
+    """Canonical event tuple: names become default-parameter events,
+    and ``"kind:t0=...,duration=...,target=...,magnitude=..."`` strings
+    (:func:`parse_fault`) the events they spell, equal to the
+    ``FaultEvent`` built directly with those parameters.
 
     Cascade entries (:class:`programs.CascadeEvent`) pass through — they
     stay unresolved until the compiler knows ``dt_ms`` and the horizon
@@ -153,7 +158,7 @@ def normalize(faults) -> Tuple[FaultEvent, ...]:
     out = []
     for f in faults:
         if isinstance(f, str):
-            f = FaultEvent(kind=f)
+            f = parse_fault(f) if ":" in f else FaultEvent(kind=f)
         elif not isinstance(f, (FaultEvent, programs.CascadeEvent)):
             raise ValueError(
                 f"SimConfig.faults entries must be fault names, "
@@ -204,7 +209,13 @@ def parse_fault(spec: str) -> FaultEvent:
                 f"bad fault parameter {tok!r} in {spec!r}; expected "
                 f"key=value with key in t0, duration, target, magnitude"
             )
-        kw[k] = float(v) if k == "magnitude" else int(v)
+        try:
+            kw[k] = float(v) if k == "magnitude" else int(v)
+        except ValueError:
+            raise ValueError(
+                f"bad fault parameter {tok!r} in {spec!r}: {k} takes "
+                f"{'a number' if k == 'magnitude' else 'a whole number'}"
+            ) from None
     return FaultEvent(kind=kind, **kw)
 
 
@@ -307,6 +318,13 @@ class FaultTickInfo(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def _compile_cached(cfg, T: int) -> CompiledFaults:
+    # the host span of the uncached compile: at N=10^6 it builds one
+    # (N,) owner table per epoch in numpy
+    with obs_trace.span("faults/compile", cat="compile", T=T, N=cfg.N):
+        return _compile(cfg, T)
+
+
+def _compile(cfg, T: int) -> CompiledFaults:
     from repro.core.faults import programs  # lazy: programs imports base
 
     # cascade entries resolve HERE: detection time needs dt_ms + horizon
@@ -356,6 +374,47 @@ def _compile_cached(cfg, T: int) -> CompiledFaults:
     )
 
 
+def counters(fc: CompiledFaults, R: int) -> Dict[str, Any]:
+    """What a compiled fault program does over its horizon, for a grid
+    of ``R`` request slots per tick: ``epochs``; ``flip_ticks``, the
+    ticks whose epoch differs from the previous tick's, and
+    ``moved_keys``, the keys whose detected-ring owner changes at each
+    (the remap invalidation's size); ``server_ticks_down`` (ground-truth
+    dead server-ticks); ``detect_delay_ticks``, the ticks from each
+    crash to its detection (a crash over before its detection has
+    none); ``storm_ticks``; and ``storm_slots``, the slot-ticks the
+    storm overlay opens (:func:`apply_traffic`: each turns into a write
+    where the workload leaves its slot idle)."""
+    flips = np.flatnonzero(fc.epoch != fc.epoch_prev)
+    moved = []
+    if fc.owner_by_epoch is not None:
+        own = fc.owner_by_epoch
+        moved = [
+            int((own[fc.epoch[t]] != own[fc.epoch_prev[t]]).sum())
+            for t in flips
+        ]
+    delays = []
+    for j in range(fc.member.shape[1]):
+        # each run of dead ticks [t0, t1) of server j
+        edges = np.diff(np.r_[0, (~fc.member[:, j]).astype(np.int8), 0])
+        for t0, t1 in zip(np.flatnonzero(edges == 1),
+                          np.flatnonzero(edges == -1)):
+            seen = np.flatnonzero(~fc.detected[t0:t1, j])
+            if seen.size:
+                delays.append(int(seen[0]))
+    r = np.arange(R)
+    tail = (R - r.astype(np.float32) - 0.5) / R
+    return {
+        "epochs": int(fc.epoch_masks.shape[0]),
+        "flip_ticks": [int(t) for t in flips],
+        "moved_keys": moved,
+        "server_ticks_down": int((~fc.member).sum()),
+        "detect_delay_ticks": delays,
+        "storm_ticks": int((fc.storm > 0).sum()),
+        "storm_slots": int((tail[None, :] < fc.storm[:, None]).sum()),
+    }
+
+
 def compile_faults(cfg, T: int) -> Optional[CompiledFaults]:
     """The compiled fault program for ``cfg`` over a T-tick horizon, or
     ``None`` when the config carries no fault events (``faults=None``
@@ -383,12 +442,14 @@ def tick_info(fc: CompiledFaults, fx: FaultXs) -> FaultTickInfo:
 
     The remap-invalidation mask diffs the per-epoch owner tables at the
     current vs. previous epoch — all-False except on a flip tick, where
-    it marks exactly the keys whose detected-ring owner changed.
+    it marks exactly the keys whose detected-ring owner changed.  The
+    diff runs under the named scope ``inval``.
     """
     inval = None
     if fc.has_remap:
-        owners = jnp.asarray(fc.owner_by_epoch)
-        inval = owners[fx.epoch] != owners[fx.epoch_prev]
+        with jax.named_scope("inval"):
+            owners = jnp.asarray(fc.owner_by_epoch)
+            inval = owners[fx.epoch] != owners[fx.epoch_prev]
     return FaultTickInfo(
         member=fx.member,
         detected=fx.detected,

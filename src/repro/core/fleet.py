@@ -281,6 +281,27 @@ def remap_invalidate(
     )
 
 
+def remap_counted(
+    state: FleetState, moved: jnp.ndarray, now_ms: jnp.ndarray
+) -> Tuple[FleetState, jnp.ndarray]:
+    """:func:`remap_invalidate`, and the () float32 count of the entries
+    it drops that were still live at ``now_ms``, in the converged table
+    and in each lagged snapshot."""
+    mv = cache_lib.to_table(moved, False)
+    n = jnp.sum(mv & (state.shared.expiry_ms > now_ms)) + jnp.sum(
+        mv & (state.lag_expiry > now_ms)
+    )
+    # the count reads the tables before the remap rewrites them; the
+    # barrier orders it first, so the remap still writes in place
+    n, expiry, lag = jax.lax.optimization_barrier(
+        (n.astype(jnp.float32), state.shared.expiry_ms, state.lag_expiry)
+    )
+    state = state._replace(
+        shared=state.shared._replace(expiry_ms=expiry), lag_expiry=lag
+    )
+    return remap_invalidate(state, moved), n
+
+
 def slow_fleet(
     state: FleetState,
     window_ms: float,

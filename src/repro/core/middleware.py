@@ -84,14 +84,18 @@ class Middleware:
     def on_slow(self, state: Any, cfg, knobs: Knobs) -> Any:
         return state
 
-    def on_fault(self, state: Any, info, cfg) -> Any:
+    def on_fault(self, state: Any, info, cfg, now_ms) -> Tuple[Any, Any]:
         """React to this tick's fault context (``info`` is a
         ``faults.FaultTickInfo``), called BEFORE ``on_batch`` so remap
         invalidation lands before any request of the new epoch is
         served.  Runs inside the jitted scan only when the config
-        carries a membership-changing fault schedule; default: no-op.
+        carries a membership-changing fault schedule.  Returns the new
+        state and the () float32 count of the entries it dropped that
+        were still live at ``now_ms``, over every view the stage serves
+        from, or None for a stage that keeps no per-key entries;
+        default: ``(state, None)``.
         """
-        return state
+        return state, None
 
 
 REGISTRY = registry_lib.Registry("middleware")
@@ -149,10 +153,10 @@ class CooperativeCache(Middleware):
         # hits never reach the servers
         return state, batch.mask & ~hit, jnp.sum(hit).astype(jnp.float32)
 
-    def on_fault(self, state: cache_lib.CacheState, info, cfg):
+    def on_fault(self, state: cache_lib.CacheState, info, cfg, now_ms):
         if info.inval is None:
-            return state
-        return cache_lib.remap_invalidate(state, info.inval)
+            return state, None
+        return cache_lib.remap_counted(state, info.inval, now_ms)
 
     def on_slow(self, state: cache_lib.CacheState, cfg, knobs: Knobs):
         lease = cfg.lease_ms if cfg.cache_mode == "lease" else jnp.inf
@@ -204,10 +208,10 @@ class FleetCache(Middleware):
         # hits are served by their proxy and never reach the servers
         return state, batch.mask & ~hit, jnp.sum(hit).astype(jnp.float32)
 
-    def on_fault(self, state: fleet_lib.FleetState, info, cfg):
+    def on_fault(self, state: fleet_lib.FleetState, info, cfg, now_ms):
         if info.inval is None:
-            return state
-        return fleet_lib.remap_invalidate(state, info.inval)
+            return state, None
+        return fleet_lib.remap_counted(state, info.inval, now_ms)
 
     def on_slow(self, state: fleet_lib.FleetState, cfg, knobs: Knobs):
         lease = cfg.lease_ms if cfg.cache_mode == "lease" else jnp.inf

@@ -221,6 +221,9 @@ class TickOut(NamedTuple):
     eligible: jnp.ndarray  # ()
     cache_hits: jnp.ndarray  # () requests absorbed by the pipeline
     dV: jnp.ndarray  # () potential change from steering this tick
+    # () live cache entries the remap invalidation dropped this tick;
+    # only under a fault program (None otherwise)
+    remap_dropped: Optional[jnp.ndarray] = None
 
 
 class SimResult(NamedTuple):
@@ -305,9 +308,12 @@ class SummaryAcc(NamedTuple):
     steered: jnp.ndarray  # ()
     eligible: jnp.ndarray  # ()
     cache_hits: jnp.ndarray  # ()
+    remap_dropped: Optional[jnp.ndarray] = None  # () see TickOut
 
 
-def _summary_init(m: int) -> SummaryAcc:
+def _summary_init(m: int, faults: bool = False) -> SummaryAcc:
+    """Zeroed accumulators; ``faults`` (a fault program is compiled)
+    adds the remap-drop count."""
     z = jnp.zeros((), jnp.float32)
     return SummaryAcc(
         n_ticks=jnp.zeros((), jnp.int32),
@@ -321,6 +327,7 @@ def _summary_init(m: int) -> SummaryAcc:
         steered=z,
         eligible=z,
         cache_hits=z,
+        remap_dropped=z if faults else None,
     )
 
 
@@ -341,6 +348,11 @@ def _summary_update(acc: SummaryAcc, out: TickOut) -> SummaryAcc:
         steered=acc.steered + out.steered,
         eligible=acc.eligible + out.eligible,
         cache_hits=acc.cache_hits + out.cache_hits,
+        remap_dropped=(
+            None
+            if out.remap_dropped is None
+            else acc.remap_dropped + out.remap_dropped
+        ),
     )
 
 
@@ -375,6 +387,9 @@ class SummaryResult:
     f_max_timeline: Optional[np.ndarray] = None  # (T,)
     pressure: Optional[np.ndarray] = None  # (T,)
     q_mean_timeline: Optional[np.ndarray] = None  # (T,) mean queue
+    # cache entries the remap invalidation dropped while still live, over
+    # the horizon; only under a fault program
+    remap_dropped_total: Optional[float] = None
 
     # ---- paper metrics (SimResult-compatible) --------------------------
     def mean_queue(self) -> float:
@@ -431,6 +446,9 @@ def _to_summary(
         pressure=None if trace is None else np.asarray(trace.pressure),
         q_mean_timeline=(
             None if trace is None else np.asarray(trace.q_mean)
+        ),
+        remap_dropped_total=(
+            None if acc.remap_dropped is None else float(acc.remap_dropped)
         ),
     )
 
@@ -712,17 +730,24 @@ def _tick(
         )
 
     # --- fault context: remap invalidation BEFORE any stage serves -------
+    # (sub-scopes: ``inval``, the owner-table diff inside tick_info;
+    # ``remap``, the stages' on_fault, which also counts the live
+    # entries it drops)
     finfo = None
+    remap_dropped = None
     if fx is not None:
+        remap_dropped = jnp.zeros((), jnp.float32)
         with jax.named_scope("tick/faults"):
             finfo = faults_lib.tick_info(fc, fx)
             if finfo.inval is not None:
-                state = state._replace(
-                    mw=tuple(
-                        mw.on_fault(ms, finfo, cfg)
-                        for mw, ms in zip(mws, state.mw)
-                    )
-                )
+                with jax.named_scope("remap"):
+                    mw_states = []
+                    for mw, ms in zip(mws, state.mw):
+                        ms, n = mw.on_fault(ms, finfo, cfg, now_ms)
+                        mw_states.append(ms)
+                        if n is not None:
+                            remap_dropped = remap_dropped + n
+                    state = state._replace(mw=tuple(mw_states))
 
     # --- middleware pipeline: stages may absorb requests at the proxy ----
     absorbed = jnp.zeros((), jnp.float32)
@@ -910,6 +935,7 @@ def _tick(
         eligible=stats.eligible,
         cache_hits=absorbed,
         dV=stats.dV,
+        remap_dropped=remap_dropped,
     )
     return state, out
 
@@ -1048,7 +1074,9 @@ def _sweep_vmapped(
                 return (s, acc), ys
 
             (final, acc), trace = jax.lax.scan(
-                tick, (st, _summary_init(cfg.m)), grids
+                tick,
+                (st, _summary_init(cfg.m, fc is not None)),
+                grids,
             )
             return final, (acc, trace)
         return jax.lax.scan(step, st, grids)

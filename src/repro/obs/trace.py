@@ -394,9 +394,12 @@ PROGRAM_PHASES = (
 # The scopes below a phase: the stage loop opens one per middleware
 # stage, named by ``Middleware.name``, directly inside
 # ``tick/middleware``, and the fleet cache splits its per-key table
-# writes from its snapshot push inside its stage.
+# writes from its snapshot push inside its stage.  The fault context
+# splits its owner-table diff from the stages' remap invalidation.
 STAGE_PHASE = "tick/middleware"
 STAGE_SCOPES = ("scatter", "snapshot")
+FAULT_PHASE = "tick/faults"
+FAULT_SCOPES = ("inval", "remap")
 
 # registered programs by HLO module name: the newest executable, the
 # fingerprints of every program registered under the name, its map
@@ -414,20 +417,26 @@ _WRAP = re.compile(r"[\w\-]+\(|\)")
 
 def phase_of(op_name: str) -> Optional[Tuple[str, str]]:
     """(phase, sub-scope) of an instruction's ``op_name`` metadata, or
-    None outside every phase.  Only ``tick/middleware`` has sub-scopes:
-    the stage, and below it one of :data:`STAGE_SCOPES` where the stage
-    opened it (``fleet_cache/scatter``); elsewhere the sub-scope is "".
-    The last component is the primitive's own name, never a scope.
-    Transform wrappers are read through: ``vmap(vmap(sweep/feasible))``
-    is ``sweep/feasible``."""
+    None outside every phase.  Two phases have sub-scopes:
+    ``tick/middleware``, the stage, and below it one of
+    :data:`STAGE_SCOPES` where the stage opened it
+    (``fleet_cache/scatter``); and ``tick/faults``, one of
+    :data:`FAULT_SCOPES`.  Elsewhere the sub-scope is "".  The last
+    component is the primitive's own name, never a scope.  Transform
+    wrappers are read through: ``vmap(vmap(sweep/feasible))`` is
+    ``sweep/feasible``."""
     parts = [p for p in _WRAP.sub("", op_name).split("/") if p]
     for i in range(len(parts) - 1):
         phase = parts[i] + "/" + parts[i + 1]
         if phase in PROGRAM_PHASES:
-            rest = parts[i + 2:-1] if phase == STAGE_PHASE else []
-            sub = rest[:1]
-            if rest[1:2] and rest[1] in STAGE_SCOPES:
-                sub.append(rest[1])
+            rest = parts[i + 2:-1]
+            sub = []
+            if phase == STAGE_PHASE:
+                sub = rest[:1]
+                if rest[1:2] and rest[1] in STAGE_SCOPES:
+                    sub.append(rest[1])
+            elif phase == FAULT_PHASE:
+                sub = [p for p in rest[:1] if p in FAULT_SCOPES]
             return phase, "/".join(sub)
     return None
 

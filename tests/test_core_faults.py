@@ -81,6 +81,68 @@ def test_parse_fault_cli_specs():
         faults.parse_fault("proxy_crash:frequency=3")
 
 
+# the fault program of the benchmark's checkpoint-storm deployment, as
+# its configuration file spells it
+CKPT_CRASH = (
+    "ckpt_storm_fleet:t0=60,duration=120,magnitude=0.6",
+    "proxy_crash:t0=80,duration=100,target=0",
+)
+
+
+@pytest.mark.parametrize(
+    "spec,event",
+    [
+        (CKPT_CRASH[0], FaultEvent("ckpt_storm_fleet", t0=60, duration=120,
+                                   magnitude=0.6)),
+        (CKPT_CRASH[1], FaultEvent("proxy_crash", t0=80, duration=100,
+                                   target=0)),
+        ("server_brownout: target=3 ,magnitude=0.25",
+         FaultEvent("server_brownout", target=3, magnitude=0.25)),
+        ("proxy_join:", FaultEvent("proxy_join")),
+        # a bare name keeps its meaning: the default event
+        ("gossip_partition", FaultEvent("gossip_partition")),
+    ],
+    ids=["storm", "crash", "spaces", "no_parameters", "bare_name"],
+)
+def test_a_fault_string_is_the_event_built_directly(spec, event):
+    a = _cfg(faults=(spec,))
+    b = _cfg(faults=(event,))
+    assert a.faults == (event,)
+    assert a == b and hash(a) == hash(b)
+
+
+@pytest.mark.parametrize(
+    "spec,culprit",
+    [
+        ("proxy_crash:t0=80,frequency=3", "frequency=3"),
+        ("proxy_crash:t0=eighty", "t0=eighty"),
+        ("proxy_crash:magnitude=high", "magnitude=high"),
+        ("proxy_crash:kind=proxy_join", "kind=proxy_join"),
+        ("power_cut:t0=1", "power_cut"),
+        ("power_cut", "power_cut"),
+        ("proxy_crash:target=99", "target"),
+    ],
+    ids=["key", "int_value", "float_value", "kind_key", "kind",
+         "bare_kind", "range"],
+)
+def test_a_bad_fault_string_is_refused_naming_it(spec, culprit):
+    with pytest.raises(ValueError, match=culprit):
+        _cfg(faults=(spec,))
+
+
+def test_the_ckpt_crash_program_flips_twice_inside_the_horizon():
+    fc = faults.compile_faults(_cfg(P=16, faults=CKPT_CRASH), 240)
+    assert fc.epoch_masks.shape[0] == 3
+    flips = np.flatnonzero(fc.epoch != fc.epoch_prev)
+    # detected 10 ticks (500 ms) after the crash; rejoin at its end
+    assert flips.tolist() == [90, 180]
+    assert not fc.epoch_masks[1][0] and fc.epoch_masks[1][1:].all()
+    assert fc.epoch_masks[0].all() and fc.epoch_masks[2].all()
+    assert fc.has_storm and fc.has_remap and fc.has_downtime
+    assert (fc.storm[60:180] == np.float32(0.6)).all()
+    assert not fc.storm[:60].any() and not fc.storm[180:].any()
+
+
 def test_all_dead_schedule_rejected():
     cfg = _cfg(m=2, faults=(
         FaultEvent("proxy_crash", t0=10, duration=50, target=0),

@@ -1,6 +1,7 @@
 """Proxy fleet: Δ=0 equivalence contract, gossip-delayed visibility,
 the write-pressure install guard, the lane-tiled tables against the
 logical ``(N,)`` model, and eager SimConfig validation."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -188,6 +189,42 @@ def test_fleet_remap_invalidate_drops_the_same_entries(N):
                    lag_expiry=jnp.where(moved, 0.0, f.lag_expiry))
     logical.assert_matches(fleet_lib.remap_invalidate(fl, moved), fresh, f,
                            N)
+
+
+
+@pytest.mark.parametrize("N", [1000, 4097])
+def test_remap_counted_counts_the_live_entries_the_remap_drops(N):
+    """The converged table's count, and the fleet's over that table and
+    every ring slot, are the moved entries whose expiry lies past the
+    clock: the entries the remap turns from live to never-live.  The
+    state it returns is :func:`remap_invalidate`'s."""
+    rng = np.random.default_rng(13)
+    D, now = 3, jnp.asarray(5e5, jnp.float32)
+    fresh = fleet_lib.init_fleet(N, 2, D)
+    expiry = rng.uniform(1.0, 1e6, N).astype(np.float32)
+    lag = rng.uniform(1.0, 1e6, (D, N)).astype(np.float32)
+    fl = fresh._replace(
+        shared=fresh.shared._replace(
+            expiry_ms=cache_lib.to_table(jnp.asarray(expiry), 0.0)),
+        lag_expiry=cache_lib.to_table(jnp.asarray(lag), 0.0),
+    )
+    moved = jnp.asarray(rng.random(N) < 0.3)
+    want = int((np.asarray(moved) & (expiry > now)).sum())
+    c, got = cache_lib.remap_counted(fl.shared, moved, now)
+    assert got.dtype == jnp.float32 and int(got) == want > 0
+    jax.tree_util.tree_map(
+        np.testing.assert_array_equal, c,
+        cache_lib.remap_invalidate(fl.shared, moved),
+    )
+    want += int((np.asarray(moved) & (lag > now)).sum())
+    after, got = fleet_lib.remap_counted(fl, moved, now)
+    assert int(got) == want
+    jax.tree_util.tree_map(
+        np.testing.assert_array_equal, after,
+        fleet_lib.remap_invalidate(fl, moved),
+    )
+    # nothing moved is live after the remap: a second count is 0
+    assert int(fleet_lib.remap_counted(after, moved, now)[1]) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -208,6 +208,112 @@ def test_phase_of_reads_scopes_through_transform_wrappers():
     assert trace_lib.phase_of("jit(_run_scan_sweep)/while") is None
 
 
+@pytest.mark.parametrize(
+    "scope,want",
+    [
+        ("tick/faults/inval/ne", ("tick/faults", "inval")),
+        ("tick/faults/inval/jit(_where)/select_n", ("tick/faults", "inval")),
+        ("tick/faults/remap/select_n", ("tick/faults", "remap")),
+        ("tick/faults/remap/fleet_cache/select_n", ("tick/faults", "remap")),
+        # the last component is the primitive, and JAX's own scopes are
+        # no sub-scope of the fault phase
+        ("tick/faults/remap", ("tick/faults", "")),
+        ("tick/faults/_where/select_n", ("tick/faults", "")),
+        # the middleware's sub-scopes map as before
+        ("tick/middleware/fleet_cache/snapshot/dynamic_update_slice",
+         ("tick/middleware", "fleet_cache/snapshot")),
+        ("tick/middleware/cache/inval/add", ("tick/middleware", "cache")),
+    ],
+)
+def test_ops_under_the_fault_scopes_map_to_them(scope, want):
+    pre = "jit(_run_scan_sweep)/vmap(vmap(while))/body/closed_call/"
+    assert trace_lib.phase_of(pre + scope) == want
+
+
+def _lowered_digest(cfg):
+    """sha256 of the sweep program as lowered for ``cfg`` (StableHLO
+    text with no source locations): what the compiler is given."""
+    import hashlib
+
+    import jax.numpy as jnp
+
+    wls = [make_workload(n, T=T, m=M, seed=0, N=cfg.N)
+           for n in ("bursty", "rename_storm")]
+    states = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs),
+        *[sim.init_state(dataclasses.replace(cfg, seed=s), 0.5, 400.0)
+          for s in (1, 2)],
+    )
+    grids = [jnp.stack([getattr(w, f) for w in wls])
+             for f in ("keys", "mask", "is_write")]
+    text = sim._run_scan_sweep.lower(cfg, states, *grids, "summary").as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+# the digests of the fault-free programs as lowered before the fault
+# phase gained its sub-scopes and its remap-drop count
+FAULT_FREE = {
+    "cache": ("4dffc0673bedbfb8c0d4e4a5cbc57abc"
+              "556bd55409361159b467909bc784e0f0",
+              dict(middleware=("cache",))),
+    "fleet_cache": ("29db661a6aafa32f8cc16c4e04b4abcc"
+                    "82de81e89a3d3c9f4de72767b50c0300",
+                    dict(middleware=("fleet_cache",), **FLEET)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FAULT_FREE))
+def test_a_fault_free_sweep_is_the_program_it_was_and_counts_no_fault(
+    registry, name
+):
+    from repro.core import faults
+
+    digest, kw = FAULT_FREE[name]
+    cfg = SimConfig(m=M, N=4096, policy="midas", **kw)
+    assert _lowered_digest(cfg) == digest
+    # no fault program, so no counters and no remap-drop field
+    assert faults.compile_faults(cfg, T) is None
+    rows = run_sweep(_spec(T=T, **kw)).cells.values()
+    assert all(r.remap_dropped_total is None for r in rows)
+
+
+def test_a_faulted_sweep_splits_its_fault_phase_and_counts_its_schedule(
+    registry
+):
+    """Under a fault program that changes membership, the fault phase
+    has two sub-scopes, the owner-table diff (``inval``) and the stages'
+    remap invalidation (``remap``), the row counts the live entries the
+    remap dropped, and ``faults.counters`` gives what the compiled
+    schedule implies."""
+    from repro.core import faults
+
+    events = ("ckpt_storm_fleet:t0=5,duration=20,magnitude=0.6",
+              "proxy_crash:t0=8,duration=16,target=0")
+    spec = _spec(middleware=("fleet_cache",), T=40, **FLEET, faults=events)
+    res = run_sweep(spec)
+    pmap = trace_lib.phase_map()["jit__run_scan_sweep"]
+    assert {sub for ph, sub in pmap.values() if ph == "tick/faults"} == {
+        "inval", "remap"
+    }
+    assert all(r.remap_dropped_total >= 0 for r in res.cells.values())
+    fc = faults.compile_faults(spec.config, 40)
+    R = spec.workloads[0].keys.shape[1]
+    got = faults.counters(fc, R)
+    own = fc.owner_by_epoch
+    assert got["epochs"] == 3 == own.shape[0]
+    assert got["flip_ticks"] == [18, 24]
+    assert got["moved_keys"] == [
+        int((own[1] != own[0]).sum()), int((own[2] != own[1]).sum())
+    ]
+    assert 0 < got["moved_keys"][0] < spec.config.N
+    assert got["server_ticks_down"] == 16
+    assert got["detect_delay_ticks"] == [fc.timeout_ticks] == [10]
+    assert got["storm_ticks"] == 20
+    # the trailing 60 % of each tick's slots, over the storm's ticks
+    tail = (R - np.arange(R, dtype=np.float32) - 0.5) / R
+    assert got["storm_slots"] == 20 * int((tail < np.float32(0.6)).sum())
+
+
 def test_parse_phases_gives_an_unscoped_fusion_its_fused_phase():
     def meta(op_name):
         return f', metadata={{op_name="jit(f)/{op_name}"}}'
